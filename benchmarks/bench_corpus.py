@@ -200,27 +200,26 @@ def run_spill(smoke: bool = False) -> list[dict]:
 
 
 def run_device() -> list[dict]:
-    """Real-device leg: re-run the streamed comparison compiled (not
-    interpreted) on an accelerator backend. Skips cleanly in interpret
-    mode — the first run on a TPU host records the first non-interpret
-    validation of the streamed HBM model."""
+    """Real-device leg: the streamed comparison compiled (not
+    interpreted) on the TPU. Raises without one — a device leg never
+    falls back to the CPU."""
     if jax.default_backend() != "tpu":
-        print("# corpus_device: skipped (no TPU backend; interpret-mode "
-              "rows above carry the launch-restructuring measurement)")
-        return []
+        raise RuntimeError(
+            "corpus_device needs a TPU backend; found "
+            f"{jax.default_backend()!r}")
     return run_streamed(smoke=False)
 
 
-def main(smoke: bool = False) -> None:
+def main(smoke: bool = False, device: bool = False) -> None:
     emit("corpus_smoke" if smoke else "corpus_streamed",
          run_streamed(smoke=smoke))
     emit("corpus_spill_smoke" if smoke else "corpus_spill",
          run_spill(smoke=smoke))
-    if not smoke:
-        rows = run_device()
-        if rows:
-            emit("corpus_device", rows)
+    if device:
+        emit("corpus_device", run_device())
 
 
 if __name__ == "__main__":
-    main()
+    import sys
+
+    main(device="--device" in sys.argv[1:])

@@ -43,6 +43,7 @@ from benchmarks import (
     bench_signatures,
     bench_updates,
 )
+from repro import compile_cache
 
 SECTIONS = [
     ("algorithms", bench_algorithms.main),
@@ -70,6 +71,7 @@ def main() -> None:
         help="seconds-long CI subset: kernel pipeline parity + timing only",
     )
     args = ap.parse_args()
+    compile_cache.enable()
     if args.smoke and args.only:
         ap.error("--smoke runs a fixed subset; it cannot be combined with --only")
     if args.smoke:

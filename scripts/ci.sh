@@ -43,7 +43,8 @@ python -m benchmarks.run --smoke
 python -m repro.launch.serve_extract --requests 16 --rate 400 \
     --plan forced --check --replan
 
-# Cluster smoke leg: two replica *processes* over TCP socket channels,
+# Cluster smoke leg: two replicas (threads of one process, one device
+# each) over TCP socket channels,
 # mixed workload with live replicated deltas mid-stream; --check
 # asserts every routed response bit-identical to one_shot_reference at
 # the request's admitted epoch.

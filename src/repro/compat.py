@@ -1,43 +1,10 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""JAX helpers the installed release (JAX 0.9) lacks.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-top-level ``jax`` namespace, and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma`` in the move. All repro code imports
-``shard_map`` from here and uses the *new* spelling (``check_vma``);
-on older jax the shim translates the kwarg and delegates to the
-experimental entry point.
+``optimization_barrier`` adds the differentiation rule the primitive
+lacks. ``shard_map``, ``set_mesh`` and ``axis_size`` are imported from
+``jax`` where they are used.
 """
 from __future__ import annotations
-
-import functools
-
-try:  # jax >= 0.6: top-level export, kwarg is check_vma
-    from jax import shard_map as _shard_map
-
-    shard_map = _shard_map
-except ImportError:  # older jax: experimental module, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    @functools.wraps(_exp_shard_map)
-    def shard_map(f, *args, check_vma: bool | None = None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _exp_shard_map(f, *args, **kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh for jit bodies.
-
-    ``jax.set_mesh`` only exists on newer jax; on older releases the
-    ``Mesh`` object itself is the equivalent context manager (it installs
-    the physical mesh that ``shard_map``/``NamedSharding`` resolve axis
-    names against), so the shim just returns ``mesh``.
-    """
-    import jax
-
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 
 def _make_barrier():
@@ -73,17 +40,4 @@ def optimization_barrier(x):
     return _BARRIER(x)
 
 
-def axis_size(name: str):
-    """Size of a named mesh axis from inside a shard_map/pmap body.
-
-    ``jax.lax.axis_size`` is a newer addition; older jax gets the same
-    value as a (constant-folded) ``psum(1)`` over the axis.
-    """
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-__all__ = ["shard_map", "axis_size", "set_mesh", "optimization_barrier"]
+__all__ = ["optimization_barrier"]

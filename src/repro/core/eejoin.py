@@ -20,6 +20,7 @@ from typing import Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.cost_model import (
@@ -89,6 +90,13 @@ class PreparedPlan:
     max_entity_len: int
 
 
+#: candidates verified per step. The verify stage's [N, K] and
+#: [N, K, L] gathers have small minor axes that the TPU pads to its
+#: (8, 128) tile, so their working set is bounded by verifying larger
+#: candidate buffers in chunks of this many rows.
+VERIFY_ROWS = 1 << 17
+
+
 def side_matches(cands: dict, side: PreparedSide, result_capacity: int) -> Matches:
     """Probe + verify one prepared side over compacted candidates.
 
@@ -96,8 +104,43 @@ def side_matches(cands: dict, side: PreparedSide, result_capacity: int) -> Match
     execute it — the live-updates subsystem probes base and delta-
     segment ``PreparedSide``s of *pinned past epochs* through here
     (``updates.builders.epoch_side_matches``) while the session's
-    operator has already moved on to a compacted base.
+    operator has already moved on to a compacted base. Buffers of more
+    than ``VERIFY_ROWS`` candidates are verified chunk by chunk (a
+    ``lax.scan``) and merged in candidate order, so the result equals
+    the one-shot verify and ``count`` stays the true match total.
     """
+    n = cands["win_valid"].shape[0]
+    if n <= VERIFY_ROWS:
+        return _verify_side(cands, side, result_capacity)
+    scalars = {k: cands[k] for k in ("n_survive", "overflow")}
+    rows = {k: v for k, v in cands.items() if k not in scalars}
+
+    def step(acc, i):
+        # the last chunk slides back to stay in bounds; its rows that
+        # an earlier chunk already verified are masked invalid
+        start = jnp.minimum(i * VERIFY_ROWS, n - VERIFY_ROWS)
+        chunk = jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, start, VERIFY_ROWS),
+            rows)
+        fresh = start + jnp.arange(VERIFY_ROWS) >= i * VERIFY_ROWS
+        chunk["win_valid"] = chunk["win_valid"] & fresh
+        m = _verify_side({**chunk, **scalars}, side, result_capacity)
+        return merge_matches(acc, m, result_capacity), None
+
+    empty = Matches(
+        doc=jnp.full((result_capacity,), -1, jnp.int32),
+        pos=jnp.full((result_capacity,), -1, jnp.int32),
+        length=jnp.full((result_capacity,), -1, jnp.int32),
+        entity=jnp.full((result_capacity,), -1, jnp.int32),
+        score=jnp.zeros((result_capacity,), jnp.float32),
+        count=jnp.int32(0),
+    )
+    out, _ = jax.lax.scan(step, empty, jnp.arange(-(-n // VERIFY_ROWS)))
+    return out
+
+
+def _verify_side(cands: dict, side: PreparedSide,
+                 result_capacity: int) -> Matches:
     if side.side.algo == ALGO_INDEX:
         m: Matches | None = None
         for part in side.index_parts:

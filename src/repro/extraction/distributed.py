@@ -32,9 +32,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import hashing
 from repro.core.dictionary import PAD
@@ -117,7 +117,7 @@ def distributed_extract_index(
         ),
         check_vma=False,
     )
-    return fn(doc_tokens)
+    return jax.jit(fn)(doc_tokens)
 
 
 # --------------------------------------------------------------------------
@@ -343,7 +343,7 @@ def distributed_extract_ssjoin(
         check_vma=False,
     )
     # table shards travel as [n, ...] arrays sharded along the worker axes
-    return fn(doc_tokens, table.keys1, table.keys2, table.ents)
+    return jax.jit(fn)(doc_tokens, table.keys1, table.keys2, table.ents)
 
 
 # --------------------------------------------------------------------------
@@ -368,4 +368,4 @@ def distributed_token_histogram(
         out_specs=P(),
         check_vma=False,
     )
-    return fn(doc_tokens)
+    return jax.jit(fn)(doc_tokens)

@@ -288,6 +288,7 @@ def _compact_bit_indices(rows, max_candidates: int):
     return jnp.where(ok, idx, -1), ok
 
 
+@partial(jax.jit, static_argnames=("max_candidates",))
 def compact_candidates(base, survive, max_candidates: int):
     """Flatten surviving candidates into fixed-capacity buffers.
 
@@ -317,6 +318,7 @@ def compact_candidates(base, survive, max_candidates: int):
     )
 
 
+@partial(jax.jit, static_argnames=("max_len", "max_candidates"))
 def candidates_from_flat(doc_tokens, flat_idx, ok, n_survive, max_len: int,
                          max_candidates: int) -> dict:
     """Build the ``compact_candidates`` dict from selected flat indices.
@@ -547,9 +549,7 @@ def fused_filter_compact(
             doc_tokens, flt, max_len, NC, sig_mode,
             params.lsh.bands, params.lsh.rows, lane_width=lane_w,
         )
-        sel, ok, n = select_from_tiles(
-            counts, tiles, NC, complete_tiles=lane_w is not None
-        )
+        sel, ok, n = select_from_tiles(counts, tiles, NC)
         if var:
             keys = gather_from_tiles(counts, vkeys, NC)  # [NC, 2]
     else:

@@ -81,8 +81,7 @@ def tile_ranks(counts, capacity: int):
     return gs, within, ok, total
 
 
-def select_from_tiles(counts, cands, capacity: int,
-                      complete_tiles: bool = False):
+def select_from_tiles(counts, cands, capacity: int):
     """Merge per-tile compacted candidate lanes into one global selection.
 
     ``counts`` [G] int32 are true per-tile survivor counts (may exceed
@@ -96,21 +95,35 @@ def select_from_tiles(counts, cands, capacity: int,
     rank < capacity, so lane truncation can never hide it). Cost is
     O(G + capacity) — the [D, T] survival bitmap is never touched.
 
-    ``complete_tiles=True`` relaxes the static ``C >= capacity`` check
-    for the adaptive two-pass emit: the caller guarantees every tile's
-    lane holds *all* of its survivors (``max(counts) <= C``, enforced
-    host-side by sizing C from a count pass), under which the merge is
-    exact at any C.
+    A lane narrower than ``capacity`` must be *complete* — hold all of
+    its tile's survivors (``max(counts) <= C``) — and the merge is then
+    exact at any C. Lanes from the ``kernels.ops`` probe wrappers are:
+    sized from a count pass (adaptive two-pass) or capped at the tile's
+    window count (``fused_probe.lane_width_for``). Lanes read from
+    outside (the wire, a checkpoint) go through ``check_lanes`` first.
     """
     G, C = cands.shape
-    assert complete_tiles or C >= capacity, (
-        f"lane width {C} < capacity {capacity}: truncated lanes would be "
-        "re-read silently (see docstring invariant; pass "
-        "complete_tiles=True only when max(counts) <= lane width)"
-    )
     gs, within, ok, total = tile_ranks(counts, capacity)
     idx = cands[gs, jnp.clip(within, 0, C - 1)]
     return jnp.where(ok, idx, -1), ok, total
+
+
+def check_lanes(counts, width: int, capacity: int) -> None:
+    """Host check of the ``select_from_tiles`` invariant.
+
+    Raises ``ValueError`` when a lane of ``width`` slots, narrower than
+    the merge ``capacity``, drops survivors (some tile's count exceeds
+    ``width``): the merge would re-read clipped slots. Reads ``counts``
+    to the host only for narrow lanes.
+    """
+    if width >= capacity:
+        return
+    mx = int(np.max(np.asarray(counts), initial=0))
+    if mx > width:
+        raise ValueError(
+            f"lane width {width} < merge capacity {capacity} holds only "
+            f"{width} of a tile's {mx} survivors"
+        )
 
 
 def gather_from_tiles(counts, payload, capacity: int, fill=0):
@@ -205,10 +218,14 @@ def filter_matches(m: Matches, entity_live, capacity: int) -> Matches:
 
 
 def merge_matches(a: Matches, b: Matches, capacity: int) -> Matches:
-    """Merge two buffers into one of ``capacity`` (dedup NOT performed)."""
+    """Merge two buffers into one of ``capacity`` (dedup NOT performed).
+
+    ``count`` is the sum of both true counts, so an overflow in either
+    input (or in the merge) stays surfaced.
+    """
     doc = jnp.concatenate([a.doc, b.doc])
     hit = doc >= 0
-    return compact_matches(
+    m = compact_matches(
         hit,
         doc,
         jnp.concatenate([a.pos, b.pos]),
@@ -217,3 +234,4 @@ def merge_matches(a: Matches, b: Matches, capacity: int) -> Matches:
         jnp.concatenate([a.score, b.score]),
         capacity,
     )
+    return dataclasses.replace(m, count=(a.count + b.count).astype(jnp.int32))

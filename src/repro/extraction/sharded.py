@@ -48,12 +48,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.dictionary import PAD
 from repro.extraction import engine
 from repro.extraction.results import (
+    check_lanes,
     gather_from_tiles,
     load_lane_checkpoint,
     save_lane_checkpoint,
@@ -382,9 +383,7 @@ def stream_filter_compact(
         doc_tokens, max_len, flt, params, tile_docs,
         lane_width=lane_w, sig_mode=sig_mode,
     )
-    sel, ok, n = select_from_tiles(
-        counts, cands, NC, complete_tiles=lane_w is not None
-    )
+    sel, ok, n = select_from_tiles(counts, cands, NC)
     out = engine.candidates_from_flat(doc_tokens, sel, ok, n, max_len, NC)
     if sig_mode == SIG_MODE_VARIANT:
         out = engine.attach_variant_keys(
@@ -455,8 +454,7 @@ def shard_lane(docs, row_offset, max_len, flt, params,
         docs, max_len, flt, params, tile_docs, row_offset=row_offset,
         lane_width=lane_width, sig_mode=sig_mode, stream_stats=stream_stats,
     )
-    complete = lane_width is not None and lane_width < NC
-    sel, ok, n = select_from_tiles(counts, cands, NC, complete_tiles=complete)
+    sel, ok, n = select_from_tiles(counts, cands, NC)
     keys = None
     if sig_mode == SIG_MODE_VARIANT:
         keys = gather_from_tiles(counts, vkeys, NC)[None, :, :]
@@ -534,7 +532,8 @@ def shard_lane_steady(docs, row_offset, max_len, flt, params,
         W = round_lane_width(tile_max, NC, floor)
         counts, cands, vkeys = emit(W)
         sizing = "refit"
-    sel, ok, n = select_from_tiles(counts, cands, NC, complete_tiles=W < NC)
+    check_lanes(tile_max, cands.shape[1], NC)
+    sel, ok, n = select_from_tiles(counts, cands, NC)
     keys = None
     if sig_mode == SIG_MODE_VARIANT:
         keys = gather_from_tiles(counts, vkeys, NC)[None, :, :]
@@ -744,7 +743,12 @@ def sharded_filter_compact(
                     NC,
                     params.lane_width or MIN_LANE_WIDTH,
                 )
-            out = wave_fn_for(lane_w)(block, offs)
+            # gather the wave's lanes on one device: the merge indexes
+            # across shards (a mesh with explicit axes refuses that on
+            # sharded operands) and the verify after it is a
+            # single-device program (Mosaic kernels are not partitioned)
+            out = jax.device_put(wave_fn_for(lane_w)(block, offs),
+                                 mesh.devices.flat[0])
             wave_lanes = out[0].reshape(n_workers, NC)
             wave_totals = out[1].reshape(n_workers)
             wave_keys = out[2].reshape(n_workers, NC, 2) if var else None
@@ -761,6 +765,11 @@ def sharded_filter_compact(
 
     if store is not None and stream_stats is not None:
         store.flush_stats(stream_stats)
+    if mesh is not None:
+        # the window gather joins the lanes on the merge device, whatever
+        # the documents' placement (sharded over the mesh, the natural
+        # input here, or one device)
+        doc_tokens = jax.device_put(doc_tokens, mesh.devices.flat[0])
     counts = jnp.concatenate(totals)
     cands = jnp.concatenate(lanes, axis=0)
     sel, ok, n = select_from_tiles(counts, cands, NC)

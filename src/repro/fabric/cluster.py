@@ -28,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import socket
+import threading
 import time
 
 import numpy as np
@@ -400,33 +401,55 @@ class ClusterCoordinator:
                 pass
 
 
-# ------------------------------------------------- multi-process topology
+# ------------------------------------------------- one-process topology
 
 
-def launch_local_cluster(names, *, timeout: float = 120.0,
+class ReplicaThread(threading.Thread):
+    """``replica_main`` on a daemon thread; ``error`` keeps the exception
+    that ended the loop (``None`` after a clean shutdown)."""
+
+    def __init__(self, host: str, port: int, name: str, device):
+        super().__init__(name=f"replica-{name}", daemon=True)
+        self._args = (host, port, name, device)
+        self.error: BaseException | None = None
+
+    def run(self) -> None:
+        try:
+            replica_main(*self._args)
+        except BaseException as exc:
+            self.error = exc
+            raise
+
+
+def launch_local_cluster(names, *, devices=None, timeout: float = 120.0,
                          endpoint_timeout: float = 60.0,
                          retries: int = 3):
-    """Spawn one replica process per name; return (procs, endpoints).
+    """Start one replica per name on a ``ReplicaThread``; return
+    (threads, endpoints).
 
-    The coordinator listens on an ephemeral 127.0.0.1 port; each child
-    (``replica.replica_main``, spawn context — safe next to jax's
-    threads) connects back and announces its name in a hello frame.
+    Replicas serve from this process — one that has touched JAX holds
+    the chip, so it never starts children that need it — each pinned
+    to its own device: replica i gets ``devices[i % len(devices)]``
+    (default ``jax.devices()``; one chip per replica on a multi-chip
+    host, all on the one device of a single-device host). The
+    coordinator listens on an ephemeral 127.0.0.1 port and each replica
+    connects back over TCP and announces its name in a hello frame, so
+    frames take the same wire path as between hosts.
     ``endpoint_timeout`` is generous by default: a replica's first
     request pays jit compilation.
     """
-    import multiprocessing as mp
+    import jax
 
-    ctx = mp.get_context("spawn")
+    devs = list(devices if devices is not None else jax.devices())
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     srv.bind(("127.0.0.1", 0))
     srv.listen(len(names))
     host, port = srv.getsockname()
-    procs = []
-    for name in names:
-        p = ctx.Process(target=replica_main, args=(host, port, name),
-                        daemon=True)
-        p.start()
-        procs.append(p)
+    threads = []
+    for i, name in enumerate(names):
+        t = ReplicaThread(host, port, name, devs[i % len(devs)])
+        t.start()
+        threads.append(t)
     endpoints: dict[str, Endpoint] = {}
     srv.settimeout(timeout)
     try:
@@ -445,4 +468,4 @@ def launch_local_cluster(names, *, timeout: float = 120.0,
             f"cluster launch: expected replicas {sorted(names)}, "
             f"got {sorted(endpoints)}"
         )
-    return procs, endpoints
+    return threads, endpoints

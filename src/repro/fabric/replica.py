@@ -37,6 +37,7 @@ import socket
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.cost_model import CostParams
@@ -210,6 +211,7 @@ def verify_lanes_on_state(state, config: EEJoinConfig, docs: np.ndarray,
     """
     from repro.extraction import engine
     from repro.extraction.results import (
+        check_lanes,
         filter_matches,
         gather_from_tiles,
         merge_matches,
@@ -229,6 +231,8 @@ def verify_lanes_on_state(state, config: EEJoinConfig, docs: np.ndarray,
         count = jnp.asarray(count)
         lane = jnp.asarray(lane)
         NC = eside.params.max_candidates
+        # lanes decoded from a frame come from outside this process
+        check_lanes(count, lane.shape[1], NC)
         sel, ok, n = select_from_tiles(count, lane, NC)
         cands = engine.candidates_from_flat(
             docs_j, sel, ok, n, state.max_len, NC
@@ -248,10 +252,17 @@ def verify_lanes_on_state(state, config: EEJoinConfig, docs: np.ndarray,
 
 
 class ReplicaServer:
-    """One replica's sessions + the frame handler driving them."""
+    """One replica's sessions + the frame handler driving them.
 
-    def __init__(self, name: str):
+    ``device`` pins the replica: every frame is handled under
+    ``jax.default_device(device)``, so its sessions' structures live
+    and its requests run on that device (one chip per replica when
+    several serve from one process). ``None`` keeps JAX's default.
+    """
+
+    def __init__(self, name: str, device=None):
         self.name = name
+        self.device = device
         # build logic reuses SessionCache.get_or_create; lookup happens
         # on this dict under the *coordinator's* session key (which may
         # differ from the local fingerprint when the snapshot was taken
@@ -422,10 +433,25 @@ class ReplicaServer:
             "deltas_applied": self.deltas_applied,
             "replans_applied": self.replans_applied,
             "released_epochs": self.released_epochs,
+            "devices": sorted(self._session_devices()),
         }
+
+    def _session_devices(self) -> set[str]:
+        """Devices holding this replica's session structures."""
+        out: set[str] = set()
+        for sess in self.sessions.values():
+            for side in sess.prepared.sides:
+                out |= {str(d) for d in side.ddict.tokens.devices()}
+        return out
 
     def handle(self, frame: Frame):
         """``transport.serve_frames`` handler: dispatch one frame."""
+        if self.device is None:
+            return self._dispatch(frame)
+        with jax.default_device(self.device):
+            return self._dispatch(frame)
+
+    def _dispatch(self, frame: Frame):
         if frame.ftype == FT_SNAPSHOT:
             return self._bootstrap(frame.payload)
         if frame.ftype == FT_DELTA:
@@ -445,22 +471,24 @@ class ReplicaServer:
         )
 
 
-def replica_main(host: str, port: int, name: str,
+def replica_main(host: str, port: int, name: str, device=None,
                  idle_timeout: float = 600.0) -> None:
-    """Child-process entrypoint: connect back, announce, serve frames.
+    """Replica serve loop: connect back, announce, serve frames.
 
-    Spawned by ``cluster.launch_local_cluster`` (multiprocessing
-    ``spawn`` context — safe next to jax's thread pools). The hello
-    frame carries the replica name so the accepting coordinator can
-    map connections to ring members. ``idle_timeout`` bounds orphaned
-    children: no frame for that long and the process exits.
+    Run on a thread by ``cluster.launch_local_cluster`` — replicas share
+    the coordinator's process (a process that has touched JAX holds the
+    chip, so it must not start children that need it), each pinned to
+    its own ``device``. The hello frame carries the replica name so the
+    accepting coordinator can map connections to ring members.
+    ``idle_timeout`` bounds orphaned loops: no frame for that long and
+    the thread exits.
     """
     sock = socket.create_connection((host, port))
     channel = SocketChannel(sock)
     channel.send(encode_frame(
         FT_ACK, 0, json.dumps({"replica": name}).encode()
     ))
-    server = ReplicaServer(name)
+    server = ReplicaServer(name, device)
     try:
         serve_frames(channel, server.handle, idle_timeout=idle_timeout)
     finally:

@@ -6,7 +6,7 @@ A channel moves whole frames (opaque byte strings) with an outer
 little-endian u32 length prefix. Two real implementations:
 
 * ``LoopbackChannel`` — an in-process queue pair (tests, benches).
-* ``SocketChannel`` — a TCP stream (the multi-process CI topology and
+* ``SocketChannel`` — a TCP stream (``launch_local_cluster`` replicas and
   the real multi-host deployment).
 
 Both expose the same three methods (``send``, ``recv``, ``close``), so

@@ -26,7 +26,10 @@ Standalone kernels (pre-fusion stages, kept for comparison + fallback):
 ``minhash`` (banded signatures over compacted windows),
 ``jaccard_verify`` (weighted-containment verification).
 
-All kernels validate in interpret mode on CPU (the kernel body lowers
-through XLA); ``ops.py`` is the dispatch layer the engine calls with
-``use_kernel=True`` and selects interpret mode off-TPU.
+``ops.py`` is the dispatch layer the engine calls with
+``use_kernel=True``: kernels compile for the TPU there, run in
+interpret mode on the CPU backend (the test path; the kernel body
+lowers through XLA), and refuse any other backend.
+``tests/test_tpu_compile.py`` compiles the main-path kernels for a
+described v5e chip.
 """

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.hashing import _C1, _C2, _GOLDEN
@@ -37,3 +38,24 @@ def hash_seeded(x, seed: int):
 def combine(h, g):
     """Order-dependent combine (bit-identical to hashing.combine)."""
     return mix(h ^ (g + jnp.uint32(_GOLDEN) + (h << 6) + (h >> 2)))
+
+
+_BIAS = np.uint32(0x80000000)
+
+
+def _biased(x):
+    """uint32 -> int32 preserving order (flip the sign bit)."""
+    return jax.lax.bitcast_convert_type(x ^ _BIAS, jnp.int32)
+
+
+def umin(a, b):
+    """Elementwise uint32 minimum as an int32 compare (Mosaic has no
+    unsigned min)."""
+    return jnp.where(_biased(a) < _biased(b), a, b)
+
+
+def umin_reduce(x, axis: int = -1):
+    """uint32 minimum along ``axis`` as a biased int32 reduction (Mosaic
+    has no unsigned reductions); bit-identical to ``x.min(axis)``."""
+    m = _biased(x).min(axis=axis)
+    return jax.lax.bitcast_convert_type(m, jnp.uint32) ^ _BIAS

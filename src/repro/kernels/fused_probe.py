@@ -10,7 +10,7 @@ tensors through HBM between them; here every per-window quantity is a
 *running* recurrence over one in-register token stream:
 
     real[t]          = tok[t] != PAD
-    hit[t]           = all k Bloom probes of tok[t] set   (bitmap VMEM-resident)
+    hit[t]           = all k Bloom probes of tok[t] set   (sign bit, see below)
     valid[t, l]      = AND(real[t .. t+l])                (running-and)
     survive[t, l]    = valid[t, l] & OR(hit[t .. t+l])    (running-or)
     rmin_i[t, l]     = MIN(h_i(tok[t .. t+l]))            (running-min, i < B*R)
@@ -66,9 +66,10 @@ materialised (see ``extraction.engine.fused_filter_compact``).
 With ``candidates > 0`` the kernel also runs a *compaction epilogue*:
 the per-tile survivor count is accumulated in registers as the length
 recurrence runs, and the tile's first ``candidates``
-surviving (doc, pos, len) triples are rank-compacted (prefix-sum over
-the register-resident bit expansion) into an ascending [G, candidates]
-flat-index lane. Candidate selection then reads only these lanes — the
+surviving (doc, pos, len) triples are rank-compacted into an ascending
+[G, candidates] flat-index lane: prefix counts are exact triangular
+matmuls and the selection one-hot matmul gathers (``_emit_lanes``),
+since the TPU lowering has no cumsum, searchsorted or gather. Candidate selection then reads only these lanes — the
 last XLA pass over the full [D, T] bitmap (cumsum + searchsorted in
 ``extraction.results.select_nonzero``) disappears, which matters because
 candidate-generation traffic, not verification, dominates at scale.
@@ -86,10 +87,12 @@ passes share the NC-derived tile height (``compact_tile_height``) so
 their grids — and therefore the per-tile counts — line up exactly.
 
 Tiling: one full document row per grid row ([Bd, T] tiles) so windows
-never straddle a tile edge; the Bloom bitmap block is grid-invariant
-(loaded once, reused across steps). Validated in interpret mode on CPU;
-on TPU the bitmap gather uses dynamic VMEM indexing (minor-dim gather,
-Mosaic v4+).
+never straddle a tile edge. The Bloom probe is one XLA gather per token
+*before* the kernel (``flag_bloom_hits``): Mosaic has no arbitrary 1-D
+VMEM gather, so each token's hit rides its int32 sign bit and the
+kernel streams a single flagged token array. Compiled for the TPU
+(``tests/test_tpu_compile.py`` lowers it for a described v5e) and run
+in interpret mode on the CPU.
 
 **Streaming mode** (``fused_probe_stream_pallas``): the per-tile grid
 itself becomes an in-kernel loop. The doc array stays in HBM
@@ -98,7 +101,7 @@ through a 2-slot VMEM buffer with ``make_async_copy`` — the DMA for
 chunk g+1 is started before chunk g's recurrence runs, so one launch
 consumes an entire shard with copy-in overlapped against compute. The
 recurrence and lane epilogue are the *same functions* the grid kernel
-runs (``_probe_recurrence`` / ``_emit_lane``), so streamed outputs are
+runs (``_probe_recurrence`` / ``_emit_lanes``), so streamed outputs are
 bit-identical to the per-tile launch loop; only the packed bitmap and
 dense signature tensors are dropped (they are exactly the per-launch
 HBM round trips streaming exists to elide — see ``hbm_bytes_fused``
@@ -116,17 +119,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashing
-from repro.core.filter import _BLOOM_SEED_BASE  # single source of truth
+from repro.core.filter import token_in_filter
 from repro.core.hashing import _C1, _GOLDEN
 from repro.core.signatures import _LSH_SEED_BASE
 from repro.core.variants import VARIANT_SEEDS
 from repro.kernels._hashing import combine as _combine
 from repro.kernels._hashing import hash_seeded as _hash
 from repro.kernels._hashing import mix as _mix
+from repro.kernels._hashing import umin as _umin
 
 _MAX_U32 = 0xFFFFFFFF
 
 DEFAULT_BD = 8
+
+#: most [bd, T] words one compaction tile may hold (VMEM bound of the
+#: epilogue's per-tile prefix counts and one-hot row gathers)
+MAX_TILE_WORDS = 1 << 15
 
 #: smallest adaptive emit-pass lane width: keeps the lane store aligned
 #: and bounds recompiles (widths are rounded up to powers of two).
@@ -148,8 +156,26 @@ def compact_tile_height(D: int, T: int, candidates: int) -> int:
     two grids tile the documents identically. The narrower lanes then
     undercut even this conservative geometry (G*(1+W) vs G*(1+NC)
     ints); see ``hbm_bytes_fused(two_pass=True)`` for the full trade.
+    The height is a multiple of 8 (the int32 sublane tile) and at most
+    ``MAX_TILE_WORDS // T`` rows, so the epilogue's per-tile state fits
+    VMEM at any merge capacity; ``lane_width_for`` then caps the lane at
+    the tile's window count.
     """
-    return min(max(DEFAULT_BD, -(-4 * candidates // max(T, 1))), max(D, 1))
+    cap = max(DEFAULT_BD, MAX_TILE_WORDS // max(T, 1) // 8 * 8)
+    bd = min(max(DEFAULT_BD, -(-4 * candidates // max(T, 1))), cap)
+    return min(-(-bd // 8) * 8, max(D, 1))
+
+
+def lane_width_for(width: int, bd: int, T: int, max_len: int) -> int:
+    """Emitted lane width: ``width`` capped at the tile's window count.
+
+    A [bd, T] tile holds at most ``bd * T * max_len`` survivors, so a
+    lane that wide always holds all of them: the capped lane is
+    *complete*, and ``select_from_tiles`` merges complete lanes exactly
+    at any capacity. Every lane narrower than the merge capacity is
+    therefore complete — capped here, or sized from a count pass.
+    """
+    return min(int(width), bd * T * max_len)
 
 
 def round_lane_width(max_count: int, cap: int,
@@ -215,12 +241,39 @@ def empty_band_sigs(bands: int, rows: int) -> np.ndarray:
     return np.array(out, dtype=np.uint32)
 
 
+_HIT_FLAG = -(2**31)  # int32 sign bit: the token's Bloom hit, set host-side
+
+
+def flag_bloom_hits(doc_tokens, bits, num_bits: int, num_hashes: int):
+    """Fold each token's Bloom-filter hit into its int32 sign bit.
+
+    The probe is an XLA gather over the ``[num_bits // 32]`` word array
+    (``core.filter.token_in_filter``), run once per token before the
+    kernel: Mosaic has no arbitrary 1-D VMEM gather, so the kernel reads
+    the hit from the token stream it already streams (token ids are
+    non-negative, so bit 31 is free) and strips it with ``_split_stream``.
+    One path on every backend; the kernel never sees the bitmap.
+    """
+    hit = token_in_filter(bits, num_bits, num_hashes, doc_tokens)
+    return jnp.where(hit, doc_tokens | jnp.int32(_HIT_FLAG), doc_tokens)
+
+
+def _split_stream(enc, use_filter: bool):
+    """(tokens, real, hit) of a flagged token stream (see flag_bloom_hits)."""
+    tok = enc & jnp.int32(0x7FFFFFFF)
+    real = tok != 0  # PAD == 0
+    return tok, real, (enc < 0) if use_filter else real
+
+
+def _shift(x, fill):
+    """One window-length step: ``x[:, t] <- x[:, t + 1]``, last <- fill."""
+    col = jnp.full((x.shape[0], 1), fill, dtype=x.dtype)
+    return jnp.concatenate([x[:, 1:], col], axis=1)
+
+
 def _probe_recurrence(
-    docs,
-    bits,
+    enc,
     *,
-    num_bits: int,
-    num_hashes: int,
     max_len: int,
     bands: int,
     rows: int,
@@ -230,34 +283,23 @@ def _probe_recurrence(
 ):
     """The filter -> signature recurrence over one [Bd, T] doc tile.
 
-    Pure function of the tile's token block (plus the VMEM-resident
-    Bloom words): runs the validity/survival/signature recurrences
-    documented in the module docstring and returns ``(pack, count,
-    k1_flat, k2_flat)`` — the packed survival bitmap, the tile's true
-    survivor total, and (variant mode) the [Bd*T*L] flattened finalised
-    key streams (``None`` otherwise). ``sig_store(l, values)`` is the
-    dense-emission hook: called once per window length with the band
-    sigs (lsh) or key pair (variant) so the grid-mode kernel can store
-    them without the streaming kernel paying for a dense tensor.
+    Pure function of the tile's flagged token block: runs the
+    validity/survival/signature recurrences documented in the module
+    docstring and returns ``(pack, count, keys)`` — the packed survival
+    bitmap, the tile's true survivor total, and (variant mode) the two
+    lists of per-length [Bd, T] finalised key planes (``None``
+    otherwise). ``sig_store(l, values)`` is the dense-emission hook:
+    called once per window length with the band sigs (lsh) or key pair
+    (variant) so the grid-mode kernel can store them without the
+    streaming kernel paying for a dense tensor.
 
     Shared verbatim by the per-tile grid kernel (``_kernel``) and the
     in-kernel DMA streaming kernel (``_stream_kernel``) so the two are
-    bit-identical by construction.
+    bit-identical by construction. Only 32-bit streams are shifted and
+    masks become integers through ``jnp.where`` (Mosaic rejects i1 ->
+    i32 vector casts).
     """
-    Bd, T = docs.shape
-    real = docs != 0  # PAD == 0
-
-    if use_filter:
-        hit = jnp.ones(docs.shape, bool)
-        for k in range(num_hashes):
-            h = _hash(docs, _BLOOM_SEED_BASE + k)
-            pos = h % jnp.uint32(num_bits)
-            word = bits[(pos // 32).astype(jnp.int32)]  # VMEM gather
-            bit = (word >> (pos % 32)) & jnp.uint32(1)
-            hit = hit & (bit == 1)
-    else:
-        hit = real  # survival degenerates to validity
-
+    docs, real, _ = _split_stream(enc, use_filter)
     lsh = sig_mode == SIG_MODE_LSH
     var = sig_mode == SIG_MODE_VARIANT
     if lsh:
@@ -276,30 +318,27 @@ def _probe_recurrence(
         zero = jnp.zeros(docs.shape, dtype=jnp.uint32)
         vs1, vx1, vs2, vx2, vcnt = zero, zero, zero, zero, zero
         prev_toks: list = []  # token streams shifted by 0 .. l-1
-        vkeys1: list = []  # per-length finalised keys (lane/dense store)
+        vkeys1: list = []  # per-length finalised key planes
         vkeys2: list = []
 
     vand = jnp.ones(docs.shape, bool)
     vor = jnp.zeros(docs.shape, bool)
     pack = jnp.zeros(docs.shape, dtype=jnp.uint32)
     count = jnp.int32(0)
-    sh_real, sh_hit = real, hit
+    sh_enc = enc
     sh_hv = list(hv) if lsh else []
-    sh_tok = docs if var else None
-    zero_row = jnp.zeros((Bd, 1), bool)
-    max_row = jnp.full((Bd, 1), _MAX_U32, dtype=jnp.uint32)
-    pad_row = jnp.zeros((Bd, 1), dtype=docs.dtype)
     for l in range(max_len):
+        sh_tok, sh_real, sh_hit = _split_stream(sh_enc, use_filter)
         vand = vand & sh_real
         vor = vor | sh_hit
         surv = vand & vor
-        pack = pack | (surv.astype(jnp.uint32) << jnp.uint32(l))
+        pack = pack | jnp.where(surv, jnp.uint32(1 << l), jnp.uint32(0))
         # per-tile survivor count, accumulated as the length recurrence
         # runs (feeds the compaction epilogue / sizing pass)
-        count = count + surv.sum().astype(jnp.int32)
+        count = count + jnp.sum(jnp.where(surv, 1, 0))
         if lsh:
             for i in range(bands * rows):
-                rmin[i] = jnp.minimum(rmin[i], sh_hv[i])
+                rmin[i] = _umin(rmin[i], sh_hv[i])
             bands_l = []
             for b in range(bands):
                 band = rmin[b * rows]
@@ -323,7 +362,7 @@ def _probe_recurrence(
                            jnp.uint32(0))
             vs1, vx1 = vs1 + h1, vx1 ^ h1
             vs2, vx2 = vs2 + h2, vx2 ^ h2
-            vcnt = vcnt + contrib.astype(jnp.uint32)
+            vcnt = vcnt + jnp.where(contrib, jnp.uint32(1), jnp.uint32(0))
             # set_hash finalise (core.hashing.set_hash, bit-identical)
             fin = vcnt * jnp.uint32(_GOLDEN)
             k1 = _mix(vs1 ^ (vx1 * jnp.uint32(_C1)) ^ fin)
@@ -334,71 +373,184 @@ def _probe_recurrence(
                 sig_store(l, [k1, k2])
             prev_toks.append(sh_tok)
         if l + 1 < max_len:
-            sh_real = jnp.concatenate([sh_real[:, 1:], zero_row], axis=1)
-            sh_hit = jnp.concatenate([sh_hit[:, 1:], zero_row], axis=1)
+            sh_enc = _shift(sh_enc, 0)
             if lsh:
-                sh_hv = [
-                    jnp.concatenate([v[:, 1:], max_row], axis=1) for v in sh_hv
-                ]
-            if var:
-                sh_tok = jnp.concatenate([sh_tok[:, 1:], pad_row], axis=1)
-    k1_flat = jnp.stack(vkeys1, axis=-1).reshape(-1) if var else None
-    k2_flat = jnp.stack(vkeys2, axis=-1).reshape(-1) if var else None
-    return pack, count, k1_flat, k2_flat
+                sh_hv = [_shift(v, _MAX_U32) for v in sh_hv]
+    return pack, count, ((vkeys1, vkeys2) if var else None)
 
 
-def _emit_lane(pack, count, cand_cap: int, max_len: int):
-    """Compaction epilogue selection: first ``cand_cap`` survivors.
+# --------------------------------------------------------------------------
+# Compaction epilogue: exact prefix counts and selection on the MXU
+# --------------------------------------------------------------------------
 
-    Two-stage (word -> bit) selection, sort- and scatter-free ("the
-    k-th survivor lives where the prefix sum first reaches k"):
-    survivor density is low, so first pick the <= cand_cap tokens with
-    any surviving length (the first cand_cap set bits always live
-    inside the first cand_cap nonzero words), then rank only their
-    unpacked bits. Returns ``(flat, ok)``: the tile-local flat
-    (row*T + pos)*L + (len-1) indices of the tile's first ``cand_cap``
-    survivors and their validity lane — everything VMEM-resident, so
-    the [D, T] bitmap is never re-read from HBM to compact it.
+#: lane slots selected per epilogue step (lane width of one chunk)
+LANE_CHUNK = 512
+
+
+def lane_chunking(width: int) -> tuple[int, int]:
+    """``(chunks, chunk)`` of the [chunks, chunk] block holding a lane of
+    ``width`` slots: chunk is a multiple of the 128-lane tile and, past
+    one chunk, the chunk count a multiple of the 8-row sublane tile;
+    slots past ``width`` are padding the wrappers slice off."""
+    if width <= LANE_CHUNK:
+        return 1, -(-width // 128) * 128
+    return -(-width // (8 * LANE_CHUNK)) * 8, LANE_CHUNK
+
+
+def _small_dot(a, b, dims):
+    """Exact integer matmul for operands in [0, 256].
+
+    Such integers are exact in bf16 and the MXU accumulates in f32, so
+    every sum below 2**24 is exact: the prefix counts and one-hot
+    gathers of the epilogue stay bit-identical to their integer
+    definitions on every backend.
+    """
+    a = a.astype(jnp.float32).astype(jnp.bfloat16)
+    b = b.astype(jnp.float32).astype(jnp.bfloat16)
+    out = jax.lax.dot_general(a, b, (dims, ((), ())),
+                              preferred_element_type=jnp.float32)
+    return out.astype(jnp.int32)
+
+
+def exclusive_prefix_rows(x):
+    """``out[i] = sum(x[:i])`` down axis 0 of a small-int [n, m] matrix.
+
+    A strictly-lower-triangular 0/1 matmul (exact while entries are
+    <= 256 and column sums < 2**24); the TPU lowering has no cumsum.
+    """
+    n = x.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return _small_dot(jnp.where(c < r, 1, 0), x, ((1,), (0,)))
+
+
+def word_popcounts(pack, max_len: int):
+    """[Bd, T] int32 survivor count of each packed survival word."""
+    c = jnp.zeros(pack.shape, jnp.int32)
+    for l in range(max_len):
+        c = c + jnp.where(((pack >> l) & jnp.uint32(1)) != 0, 1, 0)
+    return c
+
+
+def _gather_row_bytes(x, onehot_rows, nbytes: int, pick):
+    """Bytes of ``x[rsel[j], tsel[j]]`` reassembled per lane slot j.
+
+    ``onehot_rows`` [Bd, J] selects one row per lane slot (MXU gather,
+    exact per byte); ``pick`` reduces the gathered [T, J] rows to the
+    selected column. Returns [1, J] uint32.
+    """
+    xi = jax.lax.bitcast_convert_type(x, jnp.int32)
+    acc = jnp.zeros((1, onehot_rows.shape[1]), jnp.int32)
+    for b in range(nbytes):
+        byte = (xi >> (8 * b)) & 0xFF
+        acc = acc | (pick(_small_dot(byte, onehot_rows, ((0,), (0,))))
+                     << (8 * b))
+    return jax.lax.bitcast_convert_type(acc, jnp.uint32)
+
+
+def _select_chunk(j, pack, c, row_ex, row_tot, max_len: int, key_planes,
+                  row_width: int):
+    """Lane slots ``j`` [1, J] -> tile-local flat survivor indices.
+
+    Slot j holds the survivor of exclusive rank j in flat (row, pos,
+    len) order: its row is the one whose [row_ex, row_ex + row_tot)
+    range holds j, its position the word whose within-row range holds
+    the remainder, its length the matching set bit of that word. Rows
+    and words are gathered with one-hot matmuls — no gather, scatter,
+    cumsum or sort in the lowering. Returns ``(flat [1, J], keys)``
+    with ``keys`` the selected (k1, k2) variant keys or ``None``; flat
+    indices number rows ``row_width`` positions apart (the unpadded
+    document length).
     """
     Bd, T = pack.shape
     L = max_len
-    lane = jax.lax.iota(jnp.int32, cand_cap)  # iota: no captured consts
-    nz = (pack != 0).reshape(-1)  # [Bd*T]
-    cw = jnp.cumsum(nz.astype(jnp.int32))
-    wk = jnp.searchsorted(cw, lane + 1, side="left").astype(jnp.int32)
-    wok = lane < jnp.minimum(cw[-1], cand_cap)
-    words = pack.reshape(-1)[jnp.minimum(wk, Bd * T - 1)]
-    words = words * wok.astype(jnp.uint32)  # [cand_cap] u32
-    sub = ((words[:, None] >> jax.lax.iota(jnp.uint32, L))
-           & jnp.uint32(1)) != 0  # [cand_cap, L]
-    cb = jnp.cumsum(sub.reshape(-1).astype(jnp.int32))
-    k = jnp.searchsorted(cb, lane + 1, side="left").astype(jnp.int32)
-    ok = lane < jnp.minimum(count, cand_cap)
-    flat = jnp.minimum(wk[jnp.minimum(k // L, cand_cap - 1)],
-                       Bd * T - 1) * L + k % L
-    return flat, ok
+    inrow = (row_ex <= j) & (j < row_ex + row_tot)  # [Bd, J] one-hot
+    onehot = jnp.where(inrow, 1, 0)
+    r_io = jax.lax.broadcasted_iota(jnp.int32, (Bd, 1), 0)
+    rsel = jnp.sum(jnp.where(inrow, r_io, 0), axis=0, keepdims=True)
+    jr = j - jnp.sum(jnp.where(inrow, row_ex, 0), axis=0, keepdims=True)
+    crow = _small_dot(c, onehot, ((0,), (0,)))  # [T, J] row counts
+    pex = exclusive_prefix_rows(crow)
+    inword = (pex <= jr) & (jr < pex + crow)  # [T, J] one-hot
+    t_io = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+    tsel = jnp.sum(jnp.where(inword, t_io, 0), axis=0, keepdims=True)
+    k = jr - jnp.sum(jnp.where(inword, pex, 0), axis=0, keepdims=True)
+
+    def pick(g):
+        return jnp.sum(jnp.where(inword, g, 0), axis=0, keepdims=True)
+
+    word = _gather_row_bytes(pack, onehot, -(-L // 8), pick)
+    lsel = jnp.zeros_like(k)
+    seen = jnp.zeros_like(k)
+    for l in range(L):
+        bit = jnp.where(((word >> l) & jnp.uint32(1)) != 0, 1, 0)
+        lsel = jnp.where((bit == 1) & (seen == k), l, lsel)
+        seen = seen + bit
+    flat = (rsel * row_width + tsel) * L + lsel
+    if key_planes is None:
+        return flat, None
+    keys = []
+    for planes in key_planes:
+        key = jnp.zeros(k.shape, jnp.uint32)
+        for l, plane in enumerate(planes):
+            key = jnp.where(lsel == l,
+                            _gather_row_bytes(plane, onehot, 4, pick), key)
+        keys.append(key)
+    return flat, keys
 
 
-def _gather_lane_keys(k1_flat, k2_flat, flat, ok, span: int):
-    """Gather both finalised variant keys at the selected flat indices.
+def _emit_lanes(pack, count, cand_cap: int, max_len: int, key_planes, store,
+                row_width: int):
+    """Compaction epilogue: the tile's first ``cand_cap`` survivors.
 
-    The dense [Bd, T, L, 2] tensor never leaves registers/VMEM, only
-    the [cand_cap, 2] payload is stored. Padded slots carry 0, the
-    set_hash of the empty window (bit-parity with window_variant_key
-    on all-PAD windows).
+    Emits, chunk by chunk, the tile-local flat (row*T + pos)*L + (len-1)
+    indices of the survivors of rank 0 .. cand_cap-1 (ascending), via
+    ``store(ci, flat, ok, keys)`` with [1, J] rows; chunks past the
+    tile's survivor count store only padding. Everything stays
+    VMEM-resident, so the [D, T] bitmap is never re-read from HBM.
     """
-    sel = jnp.clip(flat, 0, span - 1)
-    return (jnp.where(ok, k1_flat[sel], jnp.uint32(0)),
-            jnp.where(ok, k2_flat[sel], jnp.uint32(0)))
+    chunks, J = lane_chunking(cand_cap)
+    c = word_popcounts(pack, max_len)
+    row_tot = jnp.sum(c, axis=1, keepdims=True)  # [Bd, 1]
+    row_ex = jnp.sum(exclusive_prefix_rows(c), axis=1, keepdims=True)
+    live = jnp.minimum(count, cand_cap)
+
+    def body(ci, carry):
+        j = ci * J + jax.lax.broadcasted_iota(jnp.int32, (1, J), 1)
+
+        @pl.when(ci * J < live)
+        def _select():
+            flat, keys = _select_chunk(j, pack, c, row_ex, row_tot,
+                                       max_len, key_planes, row_width)
+            store(ci, flat, j < live, keys)
+
+        @pl.when(ci * J >= live)
+        def _pad():
+            store(ci, jnp.zeros((1, J), jnp.int32), j < 0, None)
+
+        return carry
+
+    jax.lax.fori_loop(0, chunks, body, 0)
+
+
+def _lane_store(cand_ref, vkey_ref, base, var: bool):
+    """``store`` callback of ``_emit_lanes`` for [nch, J] lane refs."""
+
+    def store(ci, flat, ok, keys):
+        cand_ref[pl.ds(ci, 1), :] = jnp.where(ok, base + flat, -1)
+        if var:
+            for i in range(2):
+                vkey_ref[i, pl.ds(ci, 1), :] = (
+                    jnp.where(ok, keys[i], jnp.uint32(0)) if keys is not None
+                    else jnp.zeros(ok.shape, jnp.uint32))
+
+    return store
 
 
 def _kernel(
     doc_ref,
-    bits_ref,
     packed_ref,
     *rest_refs,
-    num_bits: int,
-    num_hashes: int,
     max_len: int,
     bands: int,
     rows: int,
@@ -416,18 +568,15 @@ def _kernel(
     cand_ref = refs.pop(0) if cand_cap else None
     var = sig_mode == SIG_MODE_VARIANT
     vkey_ref = refs.pop(0) if (var and cand_cap) else None
-    docs = doc_ref[...]  # [Bd, T] int32
-    Bd, T = docs.shape
+    enc = doc_ref[...]  # [Bd, T] flagged int32 tokens
+    Bd, T = enc.shape
 
     def sig_store(l, vals):
         for i, v in enumerate(vals):
-            sig_ref[:, :, l, i] = v
+            sig_ref[:, l, i, :] = v
 
-    pack, count, k1_flat, k2_flat = _probe_recurrence(
-        docs,
-        bits_ref[...] if use_filter else None,
-        num_bits=num_bits,
-        num_hashes=num_hashes,
+    pack, count, keys = _probe_recurrence(
+        enc,
         max_len=max_len,
         bands=bands,
         rows=rows,
@@ -437,21 +586,24 @@ def _kernel(
     )
     packed_ref[...] = pack
     if count_tiles:
-        count_ref[0] = count
+        count_ref[pl.program_id(0)] = count
     if cand_cap:
-        # compaction epilogue: emit the tile's surviving (doc, pos, len)
+        # compaction epilogue: the tile's surviving (doc, pos, len)
         # triples as ascending *global* flat indices, packed to the
         # front of a fixed [cand_cap] lane.
-        L = max_len
-        flat, ok = _emit_lane(pack, count, cand_cap, L)
-        cand_ref[0, :] = jnp.where(
-            ok, pl.program_id(0) * Bd * T * L + flat, -1
-        )
-        if var:
-            # variant keys ride the lane, gathered at the selection
-            k1, k2 = _gather_lane_keys(k1_flat, k2_flat, flat, ok, Bd * T * L)
-            vkey_ref[0, :, 0] = k1
-            vkey_ref[0, :, 1] = k2
+        base = pl.program_id(0) * Bd * T * max_len
+        _emit_lanes(pack, count, cand_cap, max_len, keys,
+                    _lane_store(cand_ref.at[0], vkey_ref.at[0] if var else None,
+                                base, var), T)
+
+
+def _unchunk_lanes(cands, vkeys, width: int):
+    """[G, nch, J] lane blocks -> [G, width] (+ [G, width, 2] keys)."""
+    G = cands.shape[0]
+    cands = cands.reshape(G, -1)[:, :width]
+    if vkeys is not None:
+        vkeys = vkeys.reshape(G, 2, -1)[:, :, :width].transpose(0, 2, 1)
+    return cands, vkeys
 
 
 @functools.partial(
@@ -483,7 +635,8 @@ def fused_probe_pallas(
     bd: int = DEFAULT_BD,
     candidates: int = 0,
     count_only: bool = False,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """One-pass filter+signature probe with optional compaction epilogue.
 
@@ -498,11 +651,11 @@ def fused_probe_pallas(
       (dense mode); else ``None``;
     * with ``candidates > 0``, the in-kernel compaction epilogue:
       ``counts`` [G] int32 holds each grid tile's true survivor count
-      (scratch-accumulated; may exceed ``candidates``) and ``cands``
-      [G, candidates] int32 the tile's first ``candidates`` survivors as
-      ascending global flat (doc*T + pos)*max_len + (len-1) indices, -1
-      padded — downstream compaction reads these tiny per-tile lanes and
-      never re-reads the [D, T] bitmap (see
+      (may exceed ``candidates``) and ``cands`` [G, candidates] int32
+      the tile's first ``candidates`` survivors as ascending global
+      flat (doc*T + pos)*max_len + (len-1) indices, -1 padded —
+      downstream compaction reads these tiny per-tile lanes and never
+      re-reads the [D, T] bitmap (see
       ``extraction.results.select_from_tiles``). ``candidates`` is the
       *lane width*: callers shrink it below the merge capacity after a
       count pass (adaptive two-pass; see ``round_lane_width``);
@@ -513,56 +666,60 @@ def fused_probe_pallas(
       but skips the lane (and key) stores — the cheap sizing pass of
       the adaptive two-pass scheme. ``candidates`` then only sets the
       tile geometry via the caller's ``bd`` choice.
+
+    ``interpret`` has no default: callers choose it explicitly
+    (``kernels.ops`` derives it from the platform).
     """
     assert max_len <= 32, "packed survival bitmap holds at most 32 lengths"
     assert candidates or not count_only, "count_only needs candidates > 0"
     assert not (count_only and sig_mode != SIG_MODE_NONE), (
         "count_only is the sizing pass: signatures belong to the emit pass"
     )
+    if sig_mode not in (SIG_MODE_NONE, SIG_MODE_LSH, SIG_MODE_VARIANT):
+        raise ValueError(f"unknown sig_mode {sig_mode!r}")
     D, T = doc_tokens.shape
     bd = min(bd, D)
     Dp = -(-D // bd) * bd
     G = Dp // bd
+    if use_filter:
+        doc_tokens = flag_bloom_hits(doc_tokens, bits, num_bits, num_hashes)
     if Dp != D:
         doc_tokens = jnp.pad(doc_tokens, ((0, Dp - D), (0, 0)))
     count_tiles = candidates > 0
     cand_cap = 0 if count_only else candidates
-    dense_sigs = sig_mode == SIG_MODE_LSH or (
-        sig_mode == SIG_MODE_VARIANT and not cand_cap
-    )
+    var = sig_mode == SIG_MODE_VARIANT
+    dense_sigs = sig_mode == SIG_MODE_LSH or (var and not cand_cap)
     sig_depth = {SIG_MODE_LSH: bands, SIG_MODE_VARIANT: 2}
 
     out_shape = [jax.ShapeDtypeStruct((Dp, T), jnp.uint32)]
     out_specs = [pl.BlockSpec((bd, T), lambda i: (i, 0))]
-    if sig_mode not in (SIG_MODE_NONE, SIG_MODE_LSH, SIG_MODE_VARIANT):
-        raise ValueError(f"unknown sig_mode {sig_mode!r}")
     if dense_sigs:
+        # [D, L, S, T]: positions stay on the lane axis
         S = sig_depth[sig_mode]
         out_shape.append(
-            jax.ShapeDtypeStruct((Dp, T, max_len, S), jnp.uint32)
+            jax.ShapeDtypeStruct((Dp, max_len, S, T), jnp.uint32)
         )
         out_specs.append(
-            pl.BlockSpec((bd, T, max_len, S), lambda i: (i, 0, 0, 0))
+            pl.BlockSpec((bd, max_len, S, T), lambda i: (i, 0, 0, 0))
         )
     if count_tiles:
         out_shape.append(jax.ShapeDtypeStruct((G,), jnp.int32))
-        out_specs.append(pl.BlockSpec((1,), lambda i: (i,)))
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if cand_cap:
-        out_shape.append(jax.ShapeDtypeStruct((G, cand_cap), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, cand_cap), lambda i: (i, 0)))
-        if sig_mode == SIG_MODE_VARIANT:
+        nch, J = lane_chunking(cand_cap)
+        out_shape.append(jax.ShapeDtypeStruct((G, nch, J), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, nch, J), lambda i: (i, 0, 0)))
+        if var:
             out_shape.append(
-                jax.ShapeDtypeStruct((G, cand_cap, 2), jnp.uint32)
+                jax.ShapeDtypeStruct((G, 2, nch, J), jnp.uint32)
             )
             out_specs.append(
-                pl.BlockSpec((1, cand_cap, 2), lambda i: (i, 0, 0))
+                pl.BlockSpec((1, 2, nch, J), lambda i: (i, 0, 0, 0))
             )
 
     outs = pl.pallas_call(
         functools.partial(
             _kernel,
-            num_bits=num_bits,
-            num_hashes=num_hashes,
             max_len=max_len,
             bands=bands,
             rows=rows,
@@ -572,21 +729,20 @@ def fused_probe_pallas(
             count_tiles=count_tiles,
             cand_cap=cand_cap,
         ),
-        grid=(Dp // bd,),
-        in_specs=[
-            pl.BlockSpec((bd, T), lambda i: (i, 0)),
-            pl.BlockSpec((bits.shape[0],), lambda i: (0,)),  # grid-invariant
-        ],
+        grid=(G,),
+        in_specs=[pl.BlockSpec((bd, T), lambda i: (i, 0))],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         interpret=interpret,
-    )(doc_tokens, bits)
+    )(doc_tokens)
     outs = list(outs)
     packed = outs.pop(0)[:D]
-    sigs = outs.pop(0)[:D] if dense_sigs else None
+    sigs = outs.pop(0)[:D].transpose(0, 3, 1, 2) if dense_sigs else None
     counts = outs.pop(0) if count_tiles else None
     cands = outs.pop(0) if cand_cap else None
-    vkeys = outs.pop(0) if (cand_cap and sig_mode == SIG_MODE_VARIANT) else None
+    vkeys = outs.pop(0) if (cand_cap and var) else None
+    if cand_cap:
+        cands, vkeys = _unchunk_lanes(cands, vkeys, cand_cap)
     return packed, sigs, counts, cands, vkeys
 
 
@@ -597,12 +753,9 @@ def fused_probe_pallas(
 
 def _stream_kernel(
     offs_ref,  # [G] i32 SMEM: absolute doc-row offset of each chunk
-    doc_ref,  # [G*bd, T] i32, memory_space=ANY (stays in HBM)
-    bits_ref,  # [num_bits // 32] u32, VMEM-resident
-    counts_ref,  # [G] i32 out
-    *rest_refs,  # [cand_ref [vkey_ref]]
-    num_bits: int,
-    num_hashes: int,
+    doc_ref,  # [G*bd, T] flagged i32, memory_space=ANY (stays in HBM)
+    counts_ref,  # [G] i32 SMEM out
+    *rest_refs,  # [cand_ref [vkey_ref]] (HBM outs), then scratch
     max_len: int,
     bands: int,
     rows: int,
@@ -611,16 +764,20 @@ def _stream_kernel(
     chunks: int,
     bd: int,
     cand_cap: int,
+    row_width: int,
 ):
     """One launch consumes an entire shard: the tile loop runs *inside*
     the kernel as a ``fori_loop`` over ``chunks`` [bd, T] tiles, each
     DMA'd HBM->VMEM into a 2-slot buffer. The copy-in of tile g+1 is
     issued before tile g's recurrence runs (double buffering), so on
     real hardware the DMA engine overlaps the VPU work; per-tile
-    lane/count/key outputs come from the same ``_emit_lane`` epilogue
+    lane/count/key outputs come from the same ``_emit_lanes`` epilogue
     the grid-mode kernel uses, with the absolute row offset read from
     SMEM instead of ``pl.program_id`` — flat indices are bit-identical
-    to the per-tile launch loop at any geometry.
+    to the per-tile launch loop at any geometry. Each tile's lane is
+    assembled in VMEM and copied out to its HBM row, so VMEM holds one
+    lane, not the shard's. Rows are lane-padded to a multiple of 128
+    for the DMA; flat indices use the unpadded ``row_width``.
     """
     var = sig_mode == SIG_MODE_VARIANT
     refs = list(rest_refs)
@@ -628,9 +785,8 @@ def _stream_kernel(
     vkey_ref = refs.pop(0) if (var and cand_cap) else None
     T = doc_ref.shape[1]
     L = max_len
-    bits = bits_ref[...] if use_filter else None
 
-    def body(buf, sem):
+    def body(buf, sem, lane_buf, key_buf, out_sem):
         def dma(slot, g):
             return pltpu.make_async_copy(
                 doc_ref.at[pl.ds(g * bd, bd), :], buf.at[slot], sem.at[slot]
@@ -646,40 +802,41 @@ def _stream_kernel(
                 dma(jax.lax.rem(g + 1, 2), g + 1).start()
 
             dma(slot, g).wait()
-            docs = buf[slot]  # [bd, T]
-            pack, cnt, k1_flat, k2_flat = _probe_recurrence(
-                docs,
-                bits,
-                num_bits=num_bits,
-                num_hashes=num_hashes,
+            pack, cnt, keys = _probe_recurrence(
+                buf[slot],
                 max_len=max_len,
                 bands=bands,
                 rows=rows,
                 use_filter=use_filter,
                 sig_mode=sig_mode,
             )
-            counts_ref[pl.ds(g, 1)] = cnt[None]
+            counts_ref[g] = cnt
             if cand_cap:
-                flat, ok = _emit_lane(pack, cnt, cand_cap, L)
-                off = offs_ref[g]
-                cand_ref[pl.ds(g, 1), :] = jnp.where(
-                    ok, off * T * L + flat, -1
-                )[None]
+                _emit_lanes(pack, cnt, cand_cap, L, keys,
+                            _lane_store(lane_buf, key_buf,
+                                        offs_ref[g] * row_width * L, var),
+                            row_width)
+                out = pltpu.make_async_copy(lane_buf, cand_ref.at[g],
+                                            out_sem.at[0])
+                out.start()
                 if var:
-                    k1, k2 = _gather_lane_keys(
-                        k1_flat, k2_flat, flat, ok, bd * T * L
-                    )
-                    vkey_ref[pl.ds(g, 1), :, :] = jnp.stack(
-                        [k1, k2], axis=-1
-                    )[None]
+                    kout = pltpu.make_async_copy(key_buf, vkey_ref.at[g],
+                                                 out_sem.at[1])
+                    kout.start()
+                    kout.wait()
+                out.wait()
             return 0
 
         jax.lax.fori_loop(0, chunks, chunk, 0)
 
+    nch, J = lane_chunking(max(cand_cap, 1))
     pl.run_scoped(
         body,
         buf=pltpu.VMEM((2, bd, T), jnp.int32),
         sem=pltpu.SemaphoreType.DMA((2,)),
+        lane_buf=pltpu.VMEM((nch, J), jnp.int32),
+        key_buf=pltpu.VMEM((2, nch, J), jnp.uint32),
+        out_sem=pltpu.SemaphoreType.DMA((2,)),
     )
 
 
@@ -713,7 +870,8 @@ def fused_probe_stream_pallas(
     bd: int = DEFAULT_BD,
     candidates: int = 0,
     count_only: bool = False,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Streamed megakernel: one launch, ``G`` double-buffered DMA tiles.
 
@@ -762,23 +920,28 @@ def fused_probe_stream_pallas(
         )
     G = R // bd
     cand_cap = 0 if count_only else candidates
+    var = sig_mode == SIG_MODE_VARIANT
+    if use_filter:
+        doc_tokens = flag_bloom_hits(doc_tokens, bits, num_bits, num_hashes)
+    Tp = -(-T // 128) * 128  # PAD columns hold no valid window
+    if Tp != T:
+        doc_tokens = jnp.pad(doc_tokens, ((0, 0), (0, Tp - T)))
 
     out_shape = [jax.ShapeDtypeStruct((G,), jnp.int32)]
-    out_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)]
+    out_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     if cand_cap:
-        out_shape.append(jax.ShapeDtypeStruct((G, cand_cap), jnp.int32))
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
-        if sig_mode == SIG_MODE_VARIANT:
+        nch, J = lane_chunking(cand_cap)
+        out_shape.append(jax.ShapeDtypeStruct((G, nch, J), jnp.int32))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        if var:
             out_shape.append(
-                jax.ShapeDtypeStruct((G, cand_cap, 2), jnp.uint32)
+                jax.ShapeDtypeStruct((G, 2, nch, J), jnp.uint32)
             )
-            out_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+            out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     outs = pl.pallas_call(
         functools.partial(
             _stream_kernel,
-            num_bits=num_bits,
-            num_hashes=num_hashes,
             max_len=max_len,
             bands=bands,
             rows=rows,
@@ -787,20 +950,22 @@ def fused_probe_stream_pallas(
             chunks=G,
             bd=bd,
             cand_cap=cand_cap,
+            row_width=T,
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # row_offs
-            pl.BlockSpec(memory_space=pltpu.ANY),  # docs stay in HBM
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # Bloom words
+            pl.BlockSpec(memory_space=pl.ANY),  # docs stay in HBM
         ],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         interpret=interpret,
-    )(row_offs, doc_tokens, bits)
+    )(row_offs, doc_tokens)
     outs = [outs] if not isinstance(outs, (tuple, list)) else list(outs)
     counts = outs.pop(0)
     cands = outs.pop(0) if cand_cap else None
-    vkeys = outs.pop(0) if (cand_cap and sig_mode == SIG_MODE_VARIANT) else None
+    vkeys = outs.pop(0) if (cand_cap and var) else None
+    if cand_cap:
+        cands, vkeys = _unchunk_lanes(cands, vkeys, cand_cap)
     return counts, cands, vkeys
 
 

@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from repro.core.signatures import _LSH_SEED_BASE
 from repro.kernels._hashing import combine as _combine
 from repro.kernels._hashing import hash_seeded as _hash
+from repro.kernels._hashing import umin_reduce
 
 DEFAULT_BN = 256
 
@@ -41,7 +42,7 @@ def _kernel(tok_ref, valid_ref, out_ref, *, bands: int, rows: int):
         for r in range(rows):
             h = _hash(toks, _LSH_SEED_BASE + b * rows + r)
             h = jnp.where(valid, h, jnp.uint32(0xFFFFFFFF))
-            m = h.min(axis=-1)  # [Bn]
+            m = umin_reduce(h, axis=-1)  # [Bn]
             band = m if band is None else _combine(band, m)
         band = _combine(band, jnp.full_like(band, jnp.uint32(b + 1)))
         out_ref[:, b] = band
@@ -54,7 +55,8 @@ def minhash_pallas(
     bands: int = 4,
     rows: int = 2,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     N, L = tokens.shape
     bn = min(bn, N)

@@ -2,9 +2,10 @@
 
 These are the dispatch points the extraction engine calls when
 ``use_kernel=True``: they adapt engine-level arguments (entity-id lists,
-weight tables) to the dense tile layout the kernels consume, and select
-interpret mode off-TPU (the assignment's validation path — the kernel
-*body* still executes, in Python, on CPU).
+weight tables) to the dense tile layout the kernels consume, and choose
+the execution mode from the platform: compiled Mosaic on TPU, Pallas
+interpret mode on the CPU backend (the test path), and an error anywhere
+else — a kernel never falls back to the interpreter on an accelerator.
 """
 from __future__ import annotations
 
@@ -20,7 +21,16 @@ from repro.kernels import window_filter as _wf
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU backend, compiled on TPU, else an error."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels target TPU (compiled) or CPU (interpret mode); "
+        f"backend {backend!r} is neither — run with use_kernel=False"
+    )
 
 
 def jaccard_verify(win_tokens, ent_ids, dict_tokens, token_weight, sim_name: str):
@@ -28,9 +38,11 @@ def jaccard_verify(win_tokens, ent_ids, dict_tokens, token_weight, sim_name: str
 
     win_tokens [N, L]; ent_ids [N, K] (-1 invalid); dict_tokens [E, L];
     token_weight [V]. Returns scores [N, K] f32 (0 for invalid ids).
-    Falls back to the jnp reference for modes the kernel doesn't fuse.
+    Similarities the kernel does not fuse (anything but "extra" and
+    "missing") take the jnp path of ``core.semantics.similarity``; see
+    ``verify_impl``.
     """
-    if sim_name not in ("extra", "missing"):
+    if verify_impl(sim_name) == "jnp":
         from repro.core.semantics import similarity
 
         safe = jnp.maximum(ent_ids, 0)
@@ -45,15 +57,27 @@ def jaccard_verify(win_tokens, ent_ids, dict_tokens, token_weight, sim_name: str
     ent_w = token_weight[ent_toks] * (ent_toks != 0)
     first = first_occurrence_mask(win_tokens, xp=jnp)
     win_w = token_weight[win_tokens] * first
-    scores = _jv.jaccard_verify_pallas(
+    scores = jaccard_scores(win_tokens, win_w, ent_toks, ent_w, sim_name)
+    return jnp.where(ent_ids >= 0, scores, 0.0)
+
+
+def verify_impl(sim_name: str) -> str:
+    """Which verify implementation ``jaccard_verify`` runs for a
+    similarity: ``"pallas"`` (fused kernel) or ``"jnp"``."""
+    return "pallas" if sim_name in ("extra", "missing") else "jnp"
+
+
+def jaccard_scores(win_tokens, win_w, ent_tokens, ent_w, mode: str):
+    """Kernel-layout verify: pre-gathered [N, L] / [N, K, L] rows and
+    weights -> [N, K] f32 scores (``jaccard_verify.jaccard_verify_pallas``)."""
+    return _jv.jaccard_verify_pallas(
         win_tokens,
         win_w.astype(jnp.float32),
-        ent_toks,
+        ent_tokens,
         ent_w.astype(jnp.float32),
-        mode=sim_name,
+        mode=mode,
         interpret=_interpret(),
     )
-    return jnp.where(ent_ids >= 0, scores, 0.0)
 
 
 def minhash(tokens, valid, bands: int, rows: int):
@@ -112,9 +136,11 @@ def fused_probe_compact(
     Returns (packed, sigs, counts [G] int32, cands [G, W] int32, vkeys):
     per grid tile, the true survivor count and the tile's first ``W``
     survivors as ascending global flat window indices (-1 pad), where
-    ``W = lane_width or candidates``. With ``sig_mode="variant"`` the
-    survivors' key pairs ride along as ``vkeys`` [G, W, 2] uint32 (and
-    no dense ``sigs`` tensor is emitted). Combine across tiles with
+    ``W = lane_width or candidates``, capped at the tile's window count
+    (``fused_probe.lane_width_for`` — a capped lane is complete). With
+    ``sig_mode="variant"`` the survivors' key pairs ride along as
+    ``vkeys`` [G, W, 2] uint32 (and no dense ``sigs`` tensor is
+    emitted). Combine across tiles with
     ``extraction.results.select_from_tiles`` — no pass over ``packed``
     is needed.
 
@@ -149,8 +175,9 @@ def fused_probe_compact(
         )
     D, T = doc_tokens.shape
     bd = _fp.compact_tile_height(D, T, candidates)
-    return _probe(doc_tokens, flt, max_len, sig_mode, bands, rows,
-                  lane_width or candidates, bd=bd)
+    width = _fp.lane_width_for(lane_width or candidates, bd, T, max_len)
+    return _probe(doc_tokens, flt, max_len, sig_mode, bands, rows, width,
+                  bd=bd)
 
 
 def fused_probe_count(
@@ -233,6 +260,8 @@ def fused_probe_stream(
     if bd is None:
         bd = _fp.compact_tile_height(doc_tokens.shape[0],
                                      doc_tokens.shape[1], candidates)
+    width = _fp.lane_width_for(lane_width or candidates, bd,
+                               doc_tokens.shape[1], max_len)
     return _fp.fused_probe_stream_pallas(
         doc_tokens,
         bits,
@@ -243,7 +272,7 @@ def fused_probe_stream(
         sig_mode=sig_mode,
         use_filter=use_filter,
         bd=bd,
-        candidates=lane_width or candidates,
+        candidates=width,
         count_only=count_only,
         interpret=_interpret(),
     )

@@ -2,22 +2,16 @@
 
 This is the paper's key pruning step fused into one pass: instead of
 materialising the L x |d| candidate substrings and probing each (the
-baseline SSJoin's failure mode, §3.1), the kernel streams document tiles
-HBM->VMEM once, keeps the entire Bloom bitmap VMEM-resident (32 KiB at
-2^18 bits — sized for exactly this), and emits the [D, T, L] survival
-mask:
+baseline SSJoin's failure mode, §3.1), the per-token Bloom hits are
+probed once (an XLA gather over the bitmap words, the same explicit
+path as ``fused_probe.flag_bloom_hits``) and the kernel streams the
+[D, T] hit tiles HBM->VMEM once, emitting the [D, T, L] survival mask:
 
     hit[d, t]        = all k probes of token (d, t) set in the bitmap
     survive[d, t, l] = any(hit[d, t .. t+l])     (running-or, registers)
 
-HBM traffic: 4B/token read + L B/token written vs. the unfused path's
-L x (window materialisation + k bitmap reads). The bitmap gather uses
-dynamic VMEM indexing (Mosaic supports minor-dim gather on v4+; the
-kernel is validated in interpret mode on CPU per the assignment).
-
 Tiling: one full document row per grid row ([Bd, T] tiles) so windows
-never straddle a tile edge; the bitmap block is grid-invariant (loaded
-once, reused across steps).
+never straddle a tile edge.
 
 NOTE: the production fast path is ``fused_probe``, which subsumes this
 kernel (packed uint32 survival bitmap instead of the [D, T, L] int8
@@ -29,35 +23,23 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.filter import _BLOOM_SEED_BASE
-from repro.kernels._hashing import hash_seeded as _hash
+from repro.core.filter import token_in_filter
 
 DEFAULT_BD = 8
 
 
-def _kernel(doc_ref, bits_ref, out_ref, *, num_bits: int, num_hashes: int, max_len: int):
-    docs = doc_ref[...]  # [Bd, T] int32
-    bits = bits_ref[...]  # [num_bits // 32] uint32 (VMEM-resident)
-    hit = jnp.ones(docs.shape, bool)
-    for k in range(num_hashes):
-        h = _hash(docs, _BLOOM_SEED_BASE + k)
-        pos = h % jnp.uint32(num_bits)
-        word = bits[(pos // 32).astype(jnp.int32)]  # VMEM gather
-        bit = (word >> (pos % 32)) & jnp.uint32(1)
-        hit = hit & (bit == 1)
-
-    Bd, T = docs.shape
+def _kernel(hit_ref, out_ref, *, max_len: int):
+    hit = hit_ref[...] != 0  # [Bd, T] per-token Bloom hits
+    Bd, T = hit.shape
     acc = jnp.zeros((Bd, T), bool)
     shifted = hit
     for l in range(max_len):
         acc = acc | shifted
-        out_ref[:, :, l] = acc.astype(jnp.int8)
+        out_ref[:, :, l] = jnp.where(acc, 1, 0).astype(jnp.int8)
         if l + 1 < max_len:
             shifted = jnp.concatenate(
                 [shifted[:, 1:], jnp.zeros((Bd, 1), bool)], axis=1
@@ -74,25 +56,25 @@ def window_filter_pallas(
     num_hashes: int,
     max_len: int,
     bd: int = DEFAULT_BD,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     D, T = doc_tokens.shape
+    # Bloom probe as an XLA gather feeding the kernel (the kernel never
+    # indexes the bitmap; see fused_probe.flag_bloom_hits)
+    hits = token_in_filter(bits, num_bits, num_hashes, doc_tokens)
+    hits = jnp.where(hits, 1, 0).astype(jnp.int32)
     bd = min(bd, D)
     Dp = -(-D // bd) * bd
     if Dp != D:
-        doc_tokens = jnp.pad(doc_tokens, ((0, Dp - D), (0, 0)))
+        hits = jnp.pad(hits, ((0, Dp - D), (0, 0)))
 
     out = pl.pallas_call(
-        functools.partial(
-            _kernel, num_bits=num_bits, num_hashes=num_hashes, max_len=max_len
-        ),
+        functools.partial(_kernel, max_len=max_len),
         grid=(Dp // bd,),
-        in_specs=[
-            pl.BlockSpec((bd, T), lambda i: (i, 0)),
-            pl.BlockSpec((bits.shape[0],), lambda i: (0,)),  # grid-invariant
-        ],
+        in_specs=[pl.BlockSpec((bd, T), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bd, T, max_len), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Dp, T, max_len), jnp.int8),
         interpret=interpret,
-    )(doc_tokens, bits)
+    )(hits)
     return out[:D].astype(bool)

@@ -14,7 +14,7 @@ import traceback  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.configs.base import SHAPES  # noqa: E402
 from repro.configs.registry import (  # noqa: E402
     ARCH_IDS, get_config, shape_applicable,

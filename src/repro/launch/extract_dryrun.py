@@ -17,7 +17,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.core.cost_model import ALGO_INDEX, ALGO_SSJOIN, CostParams  # noqa: E402
 from repro.core.eejoin import EEJoinConfig, EEJoinOperator  # noqa: E402
 from repro.core.plan import PlanSide  # noqa: E402

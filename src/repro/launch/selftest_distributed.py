@@ -46,7 +46,9 @@ def main() -> None:
         num_docs=16, doc_len=64, vocab_size=512, num_entities=32, seed=7
     )
     docs = jnp.asarray(c.doc_tokens)
-    mesh = jax.make_mesh((N_DEV,), ("workers",))
+    # the extraction code is written for automatic (not explicit) mesh axes
+    mesh = jax.make_mesh((N_DEV,), ("workers",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     axes = ("workers",)
     E = c.dictionary.num_entities
 

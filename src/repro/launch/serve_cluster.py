@@ -3,9 +3,11 @@
     PYTHONPATH=src python -m repro.launch.serve_cluster \
         --replicas 2 --requests 16 --deltas 2 --check
 
-Spawns N replica *processes* on this machine (the CI stand-in for N
-hosts — same spawn path, same TCP socket channels, same wire frames),
-bootstraps a session onto each from a compacted base snapshot, then
+Starts N replicas in this process, each on its own thread and device
+(one chip per replica on a multi-chip host) and connected over TCP
+sockets to the coordinator (the stand-in for N hosts — same socket
+channels, same wire frames), bootstraps a session onto each from a
+compacted base snapshot, then
 serves a mixed workload through the cluster coordinator: requests are
 consistent-hash routed to epoch-agreed replicas while serialized
 ``DictionaryDelta``s replicate live between batches. With ``--check``
@@ -27,6 +29,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.eejoin import EEJoinConfig
 from repro.data.synth import make_corpus
 from repro.fabric.cluster import ClusterCoordinator, launch_local_cluster
@@ -82,6 +85,7 @@ def main(argv=None) -> int:
                          "compilation on the replica)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     corpus, docs, rng = build_workload(args)
     cfg = EEJoinConfig(
@@ -94,10 +98,10 @@ def main(argv=None) -> int:
 
     names = [f"replica{i}" for i in range(args.replicas)]
     t0 = time.perf_counter()
-    procs, endpoints = launch_local_cluster(
+    threads, endpoints = launch_local_cluster(
         names, endpoint_timeout=args.timeout
     )
-    print(f"[serve_cluster] spawned {len(procs)} replica process(es) in "
+    print(f"[serve_cluster] started {len(threads)} replica(s) in "
           f"{time.perf_counter() - t0:.1f}s: {', '.join(names)}")
     metrics = ServingMetrics()
     coord = ClusterCoordinator(
@@ -188,8 +192,8 @@ def main(argv=None) -> int:
               f" epoch(s), lane bytes {row['lane_bytes']}, wire tx/rx "
               f"{row['bytes_sent']}/{row['bytes_received']} B")
     coord.shutdown()
-    for p in procs:
-        p.join(timeout=30)
+    for t in threads:
+        t.join(timeout=30)
 
     if args.check:
         if failures:

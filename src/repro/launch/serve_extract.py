@@ -21,6 +21,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.eejoin import EEJoinConfig
 from repro.data.synth import make_corpus
 from repro.serving import (
@@ -84,6 +85,7 @@ def main(argv=None) -> int:
                          "triggers a replan (with --replan)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     corpus, docs = build_request_pool(args)
     cfg = EEJoinConfig(

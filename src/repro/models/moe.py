@@ -31,9 +31,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.models.layers import dense_init
 from repro.models.sharding import ShardingRules

@@ -20,9 +20,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.models.model import LM, fused_ce_loss
 from repro.train import checkpoint as ckpt_lib
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state

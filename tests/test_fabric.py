@@ -447,6 +447,22 @@ def test_replica_rejects_request_ahead_of_ack():
         assert int(meta["epoch"]) == sess.epoch
 
 
+def test_replica_refuses_lane_frame_that_drops_survivors():
+    """A decoded lane narrower than the merge capacity must hold all of
+    its survivors; one that does not is refused, not merged."""
+    from repro.fabric.replica import verify_lanes_on_state
+
+    corpus = _dense_corpus()
+    _cache, sess = _session(corpus)
+    docs = np.asarray(corpus.doc_tokens[:1])
+    lane = np.full((1, 4), -1, np.int32)
+    lane[0] = np.arange(4)
+    lanes = [(np.array([5], np.int32), lane, None)] * len(
+        sess.current_state.sides)
+    with pytest.raises(ValueError, match="holds only 4"):
+        verify_lanes_on_state(sess.current_state, sess.config, docs, lanes)
+
+
 def test_coordinator_never_routes_to_lagging_replica():
     corpus = _dense_corpus()
     _cache, sess = _session(corpus)
@@ -636,14 +652,15 @@ def test_remote_verify_through_service():
         assert all(s.requests_served == 0 for s in servers.values())
 
 
-# ------------------------------------------------- e2e parity (processes)
+# ------------------------------------------------- e2e parity (TCP replicas)
 @pytest.mark.slow
 def test_multiprocess_cluster_parity_all_schemes():
-    """The acceptance gate: >= 2 replica *processes* over TCP sockets,
-    one session per scheme, live replicated deltas mid-stream, every
-    response bit-identical to ``one_shot_reference`` at its admitted
-    epoch."""
-    procs, endpoints = launch_local_cluster(
+    """The acceptance gate: >= 2 replicas over TCP sockets (one thread
+    and device each, in this process — a JAX process never starts
+    children that need its chip), one session per scheme, live
+    replicated deltas mid-stream, every response bit-identical to
+    ``one_shot_reference`` at its admitted epoch."""
+    threads, endpoints = launch_local_cluster(
         ["p0", "p1"], endpoint_timeout=300.0
     )
     try:
@@ -676,6 +693,7 @@ def test_multiprocess_cluster_parity_all_schemes():
         assert "replicas" in metrics.summary()
     finally:
         coord.shutdown()
-        for p in procs:
-            p.join(timeout=30)
-    assert all(p.exitcode == 0 for p in procs)
+        for t in threads:
+            t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
+    assert [t.error for t in threads] == [None] * len(threads)

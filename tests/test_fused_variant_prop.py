@@ -66,7 +66,7 @@ def test_fused_variant_keys_match_oracle(D, T, L, vocab, seed):
     # dense mode: every (pos, len) key must match the oracle
     _, sigs, _, _, _ = fp.fused_probe_pallas(
         docsj, jnp.zeros((8,), jnp.uint32), 256, 1, L,
-        sig_mode="variant", use_filter=False,
+        sig_mode="variant", use_filter=False, interpret=True,
     )
     sigs = np.asarray(sigs)  # [D, T, L, 2]
     for l in range(L):
@@ -80,6 +80,7 @@ def test_fused_variant_keys_match_oracle(D, T, L, vocab, seed):
     _, _, _, cands, vkeys = fp.fused_probe_pallas(
         docsj, jnp.zeros((8,), jnp.uint32), 256, 1, L,
         sig_mode="variant", use_filter=False, candidates=16,
+        interpret=True,
     )
     cands, vkeys = np.asarray(cands), np.asarray(vkeys)
     for g in range(cands.shape[0]):

@@ -5,11 +5,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 
 from repro.configs.registry import ARCH_IDS, get_smoke_config
 from repro.models.model import build_model, lm_loss
 from repro.models.sharding import ShardingRules
-from repro.compat import set_mesh
 
 B, S = 2, 64
 

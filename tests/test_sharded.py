@@ -289,6 +289,15 @@ def test_select_from_tiles_matches_select_nonzero(G, C, capacity):
     assert int(got_n) == int(mask.sum())
 
 
+def test_check_lanes_refuses_incomplete_narrow_lanes():
+    from repro.extraction.results import check_lanes
+
+    check_lanes(np.array([4, 2], np.int32), 4, 16)   # complete
+    check_lanes(np.array([40, 2], np.int32), 16, 16)  # full width
+    with pytest.raises(ValueError, match="holds only 4 of a tile's 5"):
+        check_lanes(np.array([5, 2], np.int32), 4, 16)
+
+
 def test_select_from_tiles_complete_tiles_narrow_lanes():
     """With complete tiles (every tile's survivors fit its lane), a
     narrow C < capacity merge must equal the full-width merge."""
@@ -312,8 +321,7 @@ def test_select_from_tiles_complete_tiles_narrow_lanes():
         jnp.asarray(counts), jnp.asarray(wide), capacity
     )
     got_idx, got_ok, got_n = select_from_tiles(
-        jnp.asarray(counts), jnp.asarray(narrow), capacity,
-        complete_tiles=True,
+        jnp.asarray(counts), jnp.asarray(narrow), capacity
     )
     np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(want_idx))
     np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))

@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 
 from repro.configs.base import SHAPES, ShapeConfig
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
@@ -23,7 +24,6 @@ from repro.launch.specs import abstract_params, build_cell
 from repro.launch.tuning import default_microbatches, resolve
 from repro.models.model import build_model
 from repro.models.sharding import ShardingRules
-from repro.compat import set_mesh
 
 
 # --------------------------------------------------------------------------

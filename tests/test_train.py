@@ -7,6 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 
 from repro.configs.registry import get_smoke_config
 from repro.core.cost_model import CostParams
@@ -23,7 +24,6 @@ from repro.train.fault_tolerance import (
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state, lr_schedule
 from repro.train.trainer import TrainerConfig, make_train_step, train
 from repro.train import checkpoint as ckpt_lib
-from repro.compat import set_mesh
 
 
 @pytest.fixture(scope="module")
