@@ -228,6 +228,9 @@ def batch_phase(corpus, *, work_dir: Path, min_shards: int = 4,
                                   stream_stats=stream_stats))
     _no_overflow(mcorp, cfg.result_capacity, "execute_corpus")
     rep["streamed_launches"] = stream_stats.get("streamed_launches", 0)
+    # the filter's true survivors among the window grid, over every side
+    rep["survival_share"] = (100.0 * stream_stats["survivors"]
+                             / stream_stats["windows"])
     check(mcorp.to_set() == got,
           f"execute_corpus {len(mcorp.to_set())} matches != execute "
           f"{len(got)}")

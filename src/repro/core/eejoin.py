@@ -38,6 +38,7 @@ from repro.core.signatures import LshParams, entity_signatures
 from repro.core.stats import EEStats, gather_stats
 from repro.extraction import engine
 from repro.extraction.results import Matches, merge_matches
+from repro.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,27 +389,35 @@ class EEJoinOperator:
         persisted (one subdirectory per plan side) and an interrupted
         run resumes to bit-identical merged matches. Verification runs
         over the merged candidate buffer exactly as in ``execute``.
+        ``stream_stats`` accumulates ``spill_filter_compact``'s counters
+        over every side (``windows`` and ``survivors`` among them).
         """
         from repro.extraction import sharded as S
 
         assert self.config.use_kernel, "execute_corpus requires use_kernel=True"
         cfg = self.config
         out: Matches | None = None
-        for i, side in enumerate(prepared.sides):
-            cands = S.spill_filter_compact(
-                corpus,
-                prepared.max_entity_len,
-                side.flt,
-                side.params,
-                device_budget_bytes=cfg.device_budget_bytes,
-                shard_docs=shard_docs,
-                tile_docs=tile_docs,
-                checkpoint_dir=None if checkpoint_dir is None
-                else f"{checkpoint_dir}/side{i}",
-                stream_stats=stream_stats,
-                fail_after_shards=fail_after_shards,
-            )
-            m = self.side_matches(cands, side)
-            out = m if out is None else merge_matches(out, m, cfg.result_capacity)
+        with span("eejoin.execute_corpus"):
+            for i, side in enumerate(prepared.sides):
+                cands = S.spill_filter_compact(
+                    corpus,
+                    prepared.max_entity_len,
+                    side.flt,
+                    side.params,
+                    device_budget_bytes=cfg.device_budget_bytes,
+                    shard_docs=shard_docs,
+                    tile_docs=tile_docs,
+                    checkpoint_dir=None if checkpoint_dir is None
+                    else f"{checkpoint_dir}/side{i}",
+                    stream_stats=stream_stats,
+                    fail_after_shards=fail_after_shards,
+                )
+                with span("eejoin.verify"):
+                    m = self.side_matches(cands, side)
+                if out is None:
+                    out = m
+                else:
+                    with span("eejoin.matches.merge"):
+                        out = merge_matches(out, m, cfg.result_capacity)
         assert out is not None, "empty plan"
         return out

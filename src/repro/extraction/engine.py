@@ -52,6 +52,7 @@ from repro.extraction.results import (
 )
 from repro.extraction.substrings import window_base
 from repro.extraction.verify import dedup_hits, verify_pairs
+from repro.tracing import span
 
 _SIGKEY_SEED = 33
 # Bucket choice uses an independent hash of the signature so that bucket
@@ -353,7 +354,8 @@ def candidates_from_flat(doc_tokens, flat_idx, ok, n_survive, max_len: int,
 
 
 def candidates_from_flat_host(doc_tokens, flat_idx, ok, n_survive,
-                              max_len: int, max_candidates: int) -> dict:
+                              max_len: int, max_candidates: int
+                              ) -> tuple[dict, int]:
     """``candidates_from_flat`` with the window gather on the *host*.
 
     The spill-streaming driver selects candidates from per-shard lanes
@@ -362,32 +364,38 @@ def candidates_from_flat_host(doc_tokens, flat_idx, ok, n_survive,
     a ``np.memmap`` — fancy-indexing it touches only the ~N needed
     rows, not the file). Field-for-field and bit-identical to the
     device gather; only the produced [N, L] windows (N = NC, tiny) are
-    shipped to the device.
+    shipped to the device. Three sibling spans time the device wait,
+    the gather and the upload. Returns the fields and the true survivor
+    total (``n_survive`` as read on the host).
     """
     T = doc_tokens.shape[1]
     L = max_len
-    flat = np.asarray(flat_idx)
-    okh = np.asarray(ok)
-    safe = np.maximum(flat, 0).astype(np.int64)
-    d = safe // (T * L)
-    rem = safe % (T * L)
-    p = rem // L
-    l = rem % L  # length-1
-    rows = np.asarray(doc_tokens[d])  # [N, T]: the only corpus touch
-    cols = p[:, None] + np.arange(L)[None, :]  # [N, L]
-    toks = rows[np.arange(rows.shape[0])[:, None], np.minimum(cols, T - 1)]
-    lens_mask = (np.arange(L)[None, :] <= l[:, None]) & (cols < T)
-    toks = np.where(lens_mask & okh[:, None], toks, PAD)
-    n = np.int32(np.asarray(n_survive))
-    return dict(
-        win_tokens=jnp.asarray(toks.astype(np.int32)),
-        win_valid=jnp.asarray(okh),
-        doc=jnp.asarray(np.where(okh, d, -1).astype(np.int32)),
-        pos=jnp.asarray(np.where(okh, p, -1).astype(np.int32)),
-        length=jnp.asarray(np.where(okh, l + 1, -1).astype(np.int32)),
-        n_survive=jnp.asarray(n),
-        overflow=jnp.asarray(np.int32(max(int(n) - max_candidates, 0))),
-    )
+    with span("eejoin.windows.sync"):
+        flat = np.asarray(flat_idx)
+        okh = np.asarray(ok)
+        n = np.int32(np.asarray(n_survive))
+    with span("eejoin.windows.gather"):
+        safe = np.maximum(flat, 0).astype(np.int64)
+        d = safe // (T * L)
+        rem = safe % (T * L)
+        p = rem // L
+        l = rem % L  # length-1
+        rows = np.asarray(doc_tokens[d])  # [N, T]: the only corpus touch
+        cols = p[:, None] + np.arange(L)[None, :]  # [N, L]
+        toks = rows[np.arange(rows.shape[0])[:, None],
+                    np.minimum(cols, T - 1)]
+        lens_mask = (np.arange(L)[None, :] <= l[:, None]) & (cols < T)
+        toks = np.where(lens_mask & okh[:, None], toks, PAD)
+    with span("eejoin.windows.upload"):
+        return dict(
+            win_tokens=jnp.asarray(toks.astype(np.int32)),
+            win_valid=jnp.asarray(okh),
+            doc=jnp.asarray(np.where(okh, d, -1).astype(np.int32)),
+            pos=jnp.asarray(np.where(okh, p, -1).astype(np.int32)),
+            length=jnp.asarray(np.where(okh, l + 1, -1).astype(np.int32)),
+            n_survive=jnp.asarray(n),
+            overflow=jnp.asarray(np.int32(max(int(n) - max_candidates, 0))),
+        ), int(n)
 
 
 def attach_kernel_sigs(cands: dict, kernel_sigs, params: ExtractParams) -> dict:

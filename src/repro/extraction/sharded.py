@@ -60,6 +60,7 @@ from repro.extraction.results import (
     save_lane_checkpoint,
     select_from_tiles,
 )
+from repro.tracing import span
 
 #: default rows per streaming tile: big enough to amortise kernel launch
 #: overhead, small enough that two tiles' working sets double-buffer in
@@ -181,8 +182,8 @@ def stream_probe_tiles(
     grid stays NC-derived so counts line up with a
     ``stream_tile_counts`` sizing pass at the same geometry.
     ``stream_stats`` (mutable dict) accumulates streaming observability
-    counters: ``streamed_launches``, ``tiles_streamed``, ``dma_waits``
-    (one per in-kernel chunk).
+    counters: ``streamed_launches`` and ``tiles_streamed`` (one per
+    in-kernel chunk, each a DMA wait).
     """
     from repro.kernels import ops as kops
     from repro.kernels.fused_probe import (
@@ -216,8 +217,6 @@ def stream_probe_tiles(
                 stream_stats.get("streamed_launches", 0) + 1)
             stream_stats["tiles_streamed"] = (
                 stream_stats.get("tiles_streamed", 0) + chunks)
-            stream_stats["dma_waits"] = (
-                stream_stats.get("dma_waits", 0) + chunks)
         return counts, cands, vkeys
 
     def probe(i):
@@ -294,8 +293,6 @@ def stream_tile_counts(
                 stream_stats.get("streamed_launches", 0) + 1)
             stream_stats["tiles_streamed"] = (
                 stream_stats.get("tiles_streamed", 0) + chunks)
-            stream_stats["dma_waits"] = (
-                stream_stats.get("dma_waits", 0) + chunks)
         return counts
     return jnp.concatenate([
         kops.fused_probe_count(docs[i * td:(i + 1) * td], flt, max_len, NC)
@@ -962,11 +959,12 @@ class HostSpillStreamer:
         start = shard * self.shard_docs
         rows = min(self.shard_docs, self.corpus.rows - start)
         assert rows > 0, f"shard {shard} starts past the corpus"
-        self._buf[:rows] = self.corpus.tokens[start:start + rows]
-        if rows < self.shard_docs:
-            self._buf[rows:] = PAD
-        self.bytes_staged += self._buf.nbytes
-        return jnp.asarray(self._buf)
+        with span("eejoin.spill.stage"):
+            self._buf[:rows] = self.corpus.tokens[start:start + rows]
+            if rows < self.shard_docs:
+                self._buf[rows:] = PAD
+            self.bytes_staged += self._buf.nbytes
+            return jnp.asarray(self._buf)
 
 
 def shard_docs_for_budget(total_docs: int, seq_len: int, budget_bytes: int,
@@ -1022,6 +1020,10 @@ def spill_filter_compact(
     field-for-field identical to ``sharded_filter_compact`` on a
     resident copy.
 
+    ``stream_stats`` accumulates, besides the streaming counters,
+    ``spill_bytes_staged``, ``windows`` (the ``D * T * max_len`` window
+    grid) and ``survivors`` (the true filter survivors among them).
+
     ``fail_after_shards`` is the kill-switch test hook: raise after
     probing that many *fresh* shards this run (checkpoint loads don't
     count), simulating an interrupted job.
@@ -1068,11 +1070,14 @@ def spill_filter_compact(
                     f"spill_filter_compact: simulated interruption after "
                     f"{fresh} fresh shards (fail_after_shards test hook)"
                 )
-            lane, n, vk = shard_lane(
-                streamer.stage(s), s * spec.shard_docs, max_len, flt,
-                params, spec.tile_docs, sig_mode=sig_mode,
-                stream_stats=stream_stats,
-            )
+            docs = streamer.stage(s)
+            with span("eejoin.spill.probe"):
+                lane, n, vk = shard_lane(
+                    docs, s * spec.shard_docs, max_len, flt, params,
+                    spec.tile_docs, sig_mode=sig_mode,
+                    stream_stats=stream_stats,
+                )
+            del docs  # one staged shard on the device at a time
             if store is not None:
                 store.save(s, lane, n, vk if var else None)
             fresh += 1
@@ -1084,16 +1089,24 @@ def spill_filter_compact(
     if stream_stats is not None:
         stream_stats["spill_bytes_staged"] = (
             stream_stats.get("spill_bytes_staged", 0) + streamer.bytes_staged)
+        # the window grid the probe enumerates
+        stream_stats["windows"] = (
+            stream_stats.get("windows", 0) + D * T * max_len)
         if store is not None:
             store.flush_stats(stream_stats)
-    counts = jnp.concatenate(totals)
-    cands = jnp.concatenate(lanes, axis=0)
-    sel, ok, n = select_from_tiles(counts, cands, NC)
-    out = engine.candidates_from_flat_host(
+    with span("eejoin.lanes.merge"):
+        counts = jnp.concatenate(totals)
+        cands = jnp.concatenate(lanes, axis=0)
+        sel, ok, n = select_from_tiles(counts, cands, NC)
+    out, survivors = engine.candidates_from_flat_host(
         corpus.tokens, sel, ok, n, max_len, NC
     )
+    if stream_stats is not None:
+        stream_stats["survivors"] = (
+            stream_stats.get("survivors", 0) + survivors)
     if var:
-        out = engine.attach_variant_keys(
-            out, gather_from_tiles(counts, jnp.concatenate(keys, axis=0), NC)
-        )
+        with span("eejoin.lanes.merge"):
+            vkeys = gather_from_tiles(
+                counts, jnp.concatenate(keys, axis=0), NC)
+        out = engine.attach_variant_keys(out, vkeys)
     return out

@@ -156,8 +156,8 @@ def main(argv=None) -> int:
           f"{s['docs_per_s']:.1f} docs/s, {s['lanes_per_s']:.1f} lanes/s")
     print(f"[serve_extract] streaming: {s['streamed_launches']} streamed "
           f"launches, {s['tiles_streamed']} tiles streamed, "
-          f"{s['dma_waits']} DMA waits, {s['checkpoint_writes']} checkpoint "
-          f"writes (sizing {s['lane_sizing'] or '{}'})")
+          f"{s['checkpoint_writes']} checkpoint writes "
+          f"(sizing {s['lane_sizing'] or '{}'})")
     if args.replan:
         events = s["replan_events"]
         print(f"[serve_extract] replan: {s['replans']} trigger(s), "

@@ -8,30 +8,11 @@ so metrics cannot perturb the pipeline they observe.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 
 import numpy as np
 
 PERCENTILES = (50, 95, 99)
-
-
-def stage_trace(name: str):
-    """Profiler annotation for one pipeline stage (context manager).
-
-    ``jax.profiler.TraceAnnotation`` when the installed jax provides it
-    (the span then shows up in captured profiler traces around the
-    probe/verify stage bodies); a ``nullcontext`` otherwise — the
-    virtual-clock / ``time.perf_counter`` timings recorded alongside
-    remain the source the replan loop actually consumes, so replanning
-    never depends on profiler availability.
-    """
-    try:
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(name)
-    except Exception:  # pragma: no cover - depends on jax build
-        return contextlib.nullcontext()
 
 
 def percentiles(xs, ps=PERCENTILES) -> dict[str, float]:
@@ -102,20 +83,21 @@ class ServingMetrics:
     lane_sizing: dict = dataclasses.field(default_factory=dict)
     lanes: int = 0  # [1, NC] probe->verify handoffs (one per batch per side)
     # streamed probe path (single-launch DMA megakernel): launches taken,
-    # in-kernel tiles consumed, DMA waits issued (one per tile chunk),
-    # and checkpoint writes/hits when a probed side persists lanes —
-    # mirrors sharded.stream_probe_tiles' stream_stats keys, so the
-    # streamed path is observable like lane sizing already is.
+    # in-kernel tiles consumed (one DMA wait each), and checkpoint
+    # writes/hits when a probed side persists lanes — mirrors
+    # sharded.stream_probe_tiles' stream_stats keys, so the streamed
+    # path is observable like lane sizing already is.
     streamed_launches: int = 0
     tiles_streamed: int = 0
-    dma_waits: int = 0
     checkpoint_writes: int = 0
     checkpoint_hits: int = 0
     docs: int = 0
     overflow_windows: int = 0  # candidate-buffer overflow, summed over batches
     depth_samples: list = dataclasses.field(default_factory=list)
     occupancy_samples: list = dataclasses.field(default_factory=list)
-    batch_records: list = dataclasses.field(default_factory=list)  # per-batch rows
+    # per-batch rows; their ``*_s`` stamps share the service's clock:
+    # flush <= probe_start <= probe_end <= verify_start <= verify_end
+    batch_records: list = dataclasses.field(default_factory=list)
     latencies_s: list = dataclasses.field(default_factory=list)
     probe_s: list = dataclasses.field(default_factory=list)
     verify_s: list = dataclasses.field(default_factory=list)
@@ -170,7 +152,6 @@ class ServingMetrics:
         """
         self.streamed_launches += stream_stats.get("streamed_launches", 0)
         self.tiles_streamed += stream_stats.get("tiles_streamed", 0)
-        self.dma_waits += stream_stats.get("dma_waits", 0)
         self.checkpoint_writes += stream_stats.get("checkpoint_writes", 0)
         self.checkpoint_hits += stream_stats.get("checkpoint_hits", 0)
         if observed is not None:
@@ -180,7 +161,14 @@ class ServingMetrics:
                      n_lanes: int, flush_s: float, probe_s: float,
                      verify_s: float, overflow: int = 0,
                      epoch: int = 0, windows: int = 0,
-                     survivors: int = 0, observed=None) -> None:
+                     survivors: int = 0, observed=None, *,
+                     probe_start_s: float, probe_end_s: float,
+                     verify_start_s: float, verify_end_s: float) -> None:
+        """One completed batch. ``probe_s``/``verify_s`` are the stage
+        bodies' ``perf_counter`` durations; the ``*_s`` stamps are the
+        batch's timeline on the service's clock, so a request's
+        ``done_s - arrival_s`` splits into batch wait, probe queue,
+        probe, handoff wait and verify."""
         self.batches += 1
         self.docs += rows
         self.lanes += n_lanes
@@ -193,6 +181,10 @@ class ServingMetrics:
             "rows": rows,
             "occupancy": occupancy,
             "flush_s": flush_s,
+            "probe_start_s": probe_start_s,
+            "probe_end_s": probe_end_s,
+            "verify_start_s": verify_start_s,
+            "verify_end_s": verify_end_s,
             "probe_s": probe_s,
             "verify_s": verify_s,
             "epoch": epoch,
@@ -260,7 +252,6 @@ class ServingMetrics:
             "lane_sizing": dict(self.lane_sizing),
             "streamed_launches": self.streamed_launches,
             "tiles_streamed": self.tiles_streamed,
-            "dma_waits": self.dma_waits,
             "checkpoint_writes": self.checkpoint_writes,
             "checkpoint_hits": self.checkpoint_hits,
             "replans": self.replans,

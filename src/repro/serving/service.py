@@ -48,10 +48,11 @@ from repro.extraction.results import (
 )
 from repro.extraction.sharded import shard_lane_steady
 from repro.serving.batcher import BatcherConfig, MicroBatch, MicroBatcher
-from repro.serving.metrics import ServingMetrics, stage_trace
+from repro.serving.metrics import ServingMetrics
 from repro.serving.pools import DevicePools, make_pools
 from repro.serving.queue import AdmissionQueue, ExtractRequest
 from repro.serving.session import SessionCache
+from repro.tracing import span
 
 #: probe->verify handoff queue depth: 2 slots double-buffer the pools
 #: (verify drains batch i while probe fills batch i+1).
@@ -89,15 +90,20 @@ def one_shot_reference(session, docs, epoch: int | None = None
 class _Handoff:
     """One probed batch in flight between the pools."""
 
-    __slots__ = ("batch", "lanes", "probe_s", "windows", "survivors")
+    __slots__ = ("batch", "lanes", "probe_s", "probe_start_s", "probe_end_s",
+                 "windows", "survivors")
 
     def __init__(self, batch: MicroBatch, lanes: list, probe_s: float,
+                 probe_start_s: float, probe_end_s: float,
                  windows: int = 0, survivors: int = 0):
         self.batch = batch
         # per plan side: (count [1] i32, cand [1, NC] i32,
         #                 keys [1, NC, 2] u32 | None  — fused variant)
         self.lanes = lanes
         self.probe_s = probe_s
+        # the probe stage on the service's clock (batch timeline)
+        self.probe_start_s = probe_start_s
+        self.probe_end_s = probe_end_s
         # telemetry for the continuous-calibration loop: enumerated
         # candidate windows and true filter survivors of this batch
         self.windows = windows
@@ -332,11 +338,12 @@ class ExtractionService:
         max for the same (side, bucket, epoch) skips the count pass
         (``shard_lane_steady``; sizing decisions land in metrics).
         """
+        start_s = self.clock()
         sess = self.sessions.get(batch.session_key)
         state = sess.state_for(batch.epoch)
         dev = self.pools.probe_device(batch.batch_id)
         t0 = time.perf_counter()
-        with stage_trace("eejoin.serve.probe"):
+        with span("eejoin.serve.probe"):
             docs = jax.device_put(jnp.asarray(batch.docs), dev)
             lanes = []
             for i, eside in enumerate(state.sides):
@@ -369,7 +376,8 @@ class ExtractionService:
                 int(np.asarray(count).sum()) for count, _, _ in lanes
             )
             sess.observed.observe_docs(batch.docs)
-        return _Handoff(batch, lanes, probe_s, windows, survivors)
+        return _Handoff(batch, lanes, probe_s, start_s, self.clock(),
+                        windows, survivors)
 
     def _verify_batch(self, handoff: _Handoff) -> None:
         """Verify stage: lanes -> candidate windows -> probe+verify join.
@@ -382,15 +390,17 @@ class ExtractionService:
         from repro.extraction.results import filter_matches
         from repro.updates.builders import epoch_side_matches
 
+        start_s = self.clock()
         batch = handoff.batch
         sess = self.sessions.get(batch.session_key)
         if self.remote_verify is not None:
-            self._verify_batch_remote(handoff, sess)
+            self._verify_batch_remote(handoff, sess, start_s)
             return
         state = sess.state_for(batch.epoch)
         dev = self.pools.verify_device(batch.batch_id)
+        capacity = sess.config.result_capacity
         t0 = time.perf_counter()
-        with stage_trace("eejoin.serve.verify"):
+        with span("eejoin.serve.verify"):
             # the handoff traffic: per side one (1 + NC)-int lane, plus
             # the raw [D, T] tokens the verify pool gathers windows from
             docs = jax.device_put(jnp.asarray(batch.docs), dev)
@@ -399,34 +409,36 @@ class ExtractionService:
             for eside, (count, lane, keys) in zip(state.sides, handoff.lanes):
                 count, lane = jax.device_put((count, lane), dev)
                 NC = eside.params.max_candidates
-                sel, ok, n = select_from_tiles(count, lane, NC)
-                cands = engine.candidates_from_flat(
-                    docs, sel, ok, n, state.max_len, NC
-                )
+                with span("eejoin.lanes.merge"):
+                    sel, ok, n = select_from_tiles(count, lane, NC)
+                with span("eejoin.windows.gather"):
+                    cands = engine.candidates_from_flat(
+                        docs, sel, ok, n, state.max_len, NC
+                    )
                 if keys is not None:
                     # fused variant keys rode the handoff lane: the verify
                     # pool attaches them instead of recomputing set hashes
-                    keys = jax.device_put(keys, dev)
-                    cands = engine.attach_variant_keys(
-                        cands, gather_from_tiles(count, keys, NC)
-                    )
+                    with span("eejoin.lanes.merge"):
+                        keys = gather_from_tiles(
+                            count, jax.device_put(keys, dev), NC)
+                    cands = engine.attach_variant_keys(cands, keys)
                 overflow += int(cands["overflow"])
-                m = epoch_side_matches(
-                    cands, eside, sess.config.result_capacity
-                )
-                out = m if out is None else merge_matches(
-                    out, m, sess.config.result_capacity
-                )
+                with span("eejoin.verify"):
+                    m = epoch_side_matches(cands, eside, capacity)
+                if out is None:
+                    out = m
+                else:
+                    with span("eejoin.matches.merge"):
+                        out = merge_matches(out, m, capacity)
             if state.has_tombstones:
-                out = filter_matches(
-                    out, state.live, sess.config.result_capacity
-                )
+                with span("eejoin.matches.merge"):
+                    out = filter_matches(out, state.live, capacity)
             jax.block_until_ready(out)
         verify_s = time.perf_counter() - t0
-        self._complete(batch, out, handoff.probe_s, verify_s, overflow,
-                       windows=handoff.windows, survivors=handoff.survivors)
+        self._complete(batch, out, handoff, verify_s, overflow, start_s)
 
-    def _verify_batch_remote(self, handoff: _Handoff, sess) -> None:
+    def _verify_batch_remote(self, handoff: _Handoff, sess,
+                             start_s: float) -> None:
         """Remote verify: frame the lanes, ship, complete on the reply.
 
         The lanes come back to host memory once (they are a few KB —
@@ -439,7 +451,7 @@ class ExtractionService:
         """
         batch = handoff.batch
         t0 = time.perf_counter()
-        with stage_trace("eejoin.serve.verify_remote"):
+        with span("eejoin.serve.verify_remote"):
             lanes = [
                 (np.asarray(count), np.asarray(lane),
                  None if keys is None else np.asarray(keys))
@@ -449,13 +461,16 @@ class ExtractionService:
                 batch.session_key, batch.epoch, batch.docs, lanes
             )
         verify_s = time.perf_counter() - t0
-        self._complete(batch, matches, handoff.probe_s, verify_s, overflow,
-                       windows=handoff.windows, survivors=handoff.survivors)
+        self._complete(batch, matches, handoff, verify_s, overflow, start_s)
 
     def _complete(self, batch: MicroBatch, matches: Matches,
-                  probe_s: float, verify_s: float, overflow: int,
-                  windows: int = 0, survivors: int = 0) -> None:
-        """Fan the batch's Matches back out to its requests (host side)."""
+                  handoff: _Handoff, verify_s: float, overflow: int,
+                  verify_start_s: float) -> None:
+        """Fan the batch's Matches back out to its requests (host side).
+
+        ``handoff`` carries the batch's probe timing and telemetry; its
+        verify stage ends on the service's clock when its requests are
+        done (``ServingMetrics.record_batch``)."""
         now = self.clock()
         doc = np.asarray(matches.doc)
         pos = np.asarray(matches.pos)
@@ -490,13 +505,17 @@ class ExtractionService:
                 occupancy=batch.occupancy,
                 n_lanes=n_lanes,
                 flush_s=batch.flush_s,
-                probe_s=probe_s,
+                probe_s=handoff.probe_s,
                 verify_s=verify_s,
                 overflow=overflow,
                 epoch=batch.epoch,
-                windows=windows,
-                survivors=survivors,
+                windows=handoff.windows,
+                survivors=handoff.survivors,
                 observed=sess.observed,
+                probe_start_s=handoff.probe_start_s,
+                probe_end_s=handoff.probe_end_s,
+                verify_start_s=verify_start_s,
+                verify_end_s=now,
             )
 
     def _fail_batch(self, batch: MicroBatch, exc: Exception) -> None:

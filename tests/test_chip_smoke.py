@@ -41,6 +41,7 @@ def test_batch_phase_parity_tiny(cs, tiny, tmp_path):
                          stats_docs=16, tile_docs=8)
     assert rep["matches"] > 0 and rep["oracle_matches"] > 0
     assert rep["shards"] >= 4
+    assert 0 < rep["survival_share"] <= 100
     assert set(rep["verify_impl"].values()) <= {"pallas", "jnp"}
 
 

@@ -353,7 +353,7 @@ def test_spill_over_budget_parity(tmp_path):
     # 8 staged shard regions of 4x64 int32 each crossed the host buffer
     assert stats["spill_bytes_staged"] == 8 * 4 * 64 * 4
     assert stats["streamed_launches"] == 8  # one launch per shard
-    assert stats["tiles_streamed"] == stats["dma_waits"] > 8
+    assert stats["tiles_streamed"] > 8
 
 
 def test_spill_accepts_host_arrays(tmp_path):
@@ -400,7 +400,7 @@ def test_shard_lane_steady_stream_stats():
     )
     assert sizing == "fixed" and keys is None
     assert stats["streamed_launches"] == 1
-    assert stats["tiles_streamed"] == stats["dma_waits"] >= 3
+    assert stats["tiles_streamed"] >= 3
     # pinning streamed=False leaves the counters untouched
     stats2: dict = {}
     lane2, n2, *_ = SH.shard_lane_steady(
@@ -417,14 +417,13 @@ def test_serving_metrics_record_stream():
 
     m = ServingMetrics()
     m.record_stream({"streamed_launches": 2, "tiles_streamed": 8,
-                     "dma_waits": 8, "checkpoint_writes": 1})
+                     "checkpoint_writes": 1})
     m.record_stream({})  # per-tile probe: a no-op
     m.record_stream({"streamed_launches": 1, "tiles_streamed": 4,
-                     "dma_waits": 4, "checkpoint_hits": 3})
+                     "checkpoint_hits": 3})
     s = m.summary()
     assert s["streamed_launches"] == 3
     assert s["tiles_streamed"] == 12
-    assert s["dma_waits"] == 12
     assert s["checkpoint_writes"] == 1
     assert s["checkpoint_hits"] == 3
 
@@ -487,3 +486,38 @@ def test_execute_corpus_kill_then_resume(small_corpus, tmp_path):
     got = op.execute_corpus(prepared, corpus, shard_docs=2, tile_docs=2,
                             checkpoint_dir=ckpt).to_set()
     assert got == want
+
+
+@pytest.mark.parametrize("head,tail", [
+    (("index", "prefix"), ("ssjoin", "prefix")),
+    (("ssjoin", "prefix"), ("ssjoin", "variant")),
+])
+def test_execute_corpus_counts_survivors_and_windows(small_corpus, tmp_path,
+                                                     head, tail):
+    """``stream_stats`` gets each side's true filter survivors (the set
+    entries of ``survival_mask``) and its D x T x L window grid."""
+    from repro.core.cost_model import OBJ_JOB, SideCost
+    from repro.core.eejoin import EEJoinConfig, EEJoinOperator
+    from repro.core.plan import Plan, PlanSide
+
+    c = small_corpus
+    op = EEJoinOperator(
+        c.dictionary,
+        EEJoinConfig(gamma=GAMMA, max_candidates=4096, result_capacity=8192,
+                     use_kernel=True),
+    )
+    z = SideCost(0, 0, 0, 0, 0, 0, 0, 0, 0)
+    plan = Plan(6, PlanSide(*head), PlanSide(*tail), OBJ_JOB, 0.0, z, z, 0)
+    prepared = op.prepare(plan)
+    assert len(prepared.sides) == 2
+    corpus = SH.MemmapCorpus.write(str(tmp_path / "corpus"), c.doc_tokens)
+    stats: dict = {}
+    op.execute_corpus(prepared, corpus, shard_docs=4, tile_docs=2,
+                      stream_stats=stats)
+    D, T = c.doc_tokens.shape
+    L = prepared.max_entity_len
+    docs = jnp.asarray(c.doc_tokens)
+    want = sum(int(np.asarray(E.survival_mask(docs, L, side.flt)[1]).sum())
+               for side in prepared.sides)
+    assert 0 < stats["survivors"] == want
+    assert stats["windows"] == 2 * D * T * L
